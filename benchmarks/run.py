@@ -49,6 +49,7 @@ def bench_fig3() -> None:
         _emit(
             f"fig3.scaling.{r['task']}.shards{r['shards']}",
             r["wall_s"] * 1e6,
+            f"platform={r['platform']} "
             f"per_dev_bytes={r['per_device_bytes']:.3g} "
             f"per_dev_flops={r['per_device_flops']:.3g}",
         )
@@ -417,6 +418,9 @@ def main() -> None:
                     help="small synthetic dataset, plan-executor coverage "
                     "only — the CI regression gate")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     print("name,us_per_call,derived")
     if args.smoke:
         bench_table1()

@@ -24,7 +24,10 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 from repro.core import DCIR_SCHEMA, drug_dispenses, medical_acts_dcir
 from repro.data.io import save_star
 from repro.data.synthetic import SyntheticConfig, generate_dcir
+from repro.compile_cache import enable_compile_cache
 from repro.study import CohortQueryService, ServiceConfig, Study, col
+
+enable_compile_cache()
 
 cfg = SyntheticConfig(n_patients=2_000, seed=7)
 P = cfg.n_patients

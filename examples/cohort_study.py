@@ -21,7 +21,10 @@ from repro.core import (
     hospital_stays, medical_acts_dcir, medical_acts_pmsi, stats,
 )
 from repro.data.synthetic import SyntheticConfig, generate_snds
+from repro.compile_cache import enable_compile_cache
 from repro.study import Study, col
+
+enable_compile_cache()
 
 cfg = SyntheticConfig(n_patients=2_000, seed=42)
 P = cfg.n_patients

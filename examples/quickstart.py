@@ -20,7 +20,10 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.core import DCIR_SCHEMA, drug_dispenses, medical_acts_dcir, stats
 from repro.data.synthetic import SyntheticConfig, generate_dcir
+from repro.compile_cache import enable_compile_cache
 from repro.study import Study, column_audit_from_log, flow_rows_from_log
+
+enable_compile_cache()
 
 # 1. normalized claims data (stand-in for the CSV exports CNAM dumps)
 cfg = SyntheticConfig(n_patients=1_000, seed=0)

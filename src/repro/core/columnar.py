@@ -43,6 +43,7 @@ __all__ = [
     "ColumnarTable",
     "NULL_INT",
     "NULL_FLOAT",
+    "cumsum",
     "is_null",
 ]
 
@@ -58,6 +59,28 @@ def is_null(col: jax.Array) -> jax.Array:
     if jnp.issubdtype(col.dtype, jnp.floating):
         return jnp.isnan(col)
     return col == jnp.asarray(NULL_INT, dtype=col.dtype)
+
+
+_SCAN_ROW = 1024
+
+
+def cumsum(x: jax.Array) -> jax.Array:
+    """Inclusive prefix sum of a 1-D integer array, equal to
+    ``jnp.cumsum(x)``: scans of ``_SCAN_ROW``-long rows plus a scan of the
+    row totals.  The TPU compiler builds ``jnp.cumsum`` as one window as
+    long as the array, taking time that grows with its length (over a
+    minute at millions of rows); these short scans compile in about a
+    second."""
+    x = jnp.asarray(x)
+    if x.dtype == jnp.bool_:
+        x = x.astype(jnp.int32)
+    n = x.shape[0]
+    if n <= _SCAN_ROW:
+        return jnp.cumsum(x)
+    rows = jnp.pad(x, (0, (-n) % _SCAN_ROW)).reshape(-1, _SCAN_ROW)
+    inner = jnp.cumsum(rows, axis=1)
+    totals = inner[:, -1]
+    return (inner + (cumsum(totals) - totals)[:, None]).reshape(-1)[:n]
 
 
 def _max_key(dtype) -> jax.Array:
@@ -229,7 +252,7 @@ class ColumnarTable:
             return self
         words = self.valid
         per_word = jax.lax.population_count(words).astype(jnp.int32)
-        excl = jnp.cumsum(per_word) - per_word           # popcount cumsum
+        excl = cumsum(per_word) - per_word               # popcount cumsum
         rows = jnp.arange(cap, dtype=jnp.int32)
         w, b = rows >> 5, (rows & 31).astype(jnp.uint32)
         upto = (jnp.uint32(2) << b) - jnp.uint32(1)      # bits <= b (wraps ok)
@@ -260,16 +283,25 @@ class ColumnarTable:
         or re-packs a bool mask."""
         if self.capacity == 0:
             return self
-        rows = jnp.arange(self.capacity, dtype=jnp.int32)
+        cap = self.capacity
+        rows = jnp.arange(cap, dtype=jnp.int32)
         bit = _bs.bit_at(self.valid, rows)
-        keys = []
-        for n in reversed(list(names)):  # lexsort: LAST key is primary
+        # Least significant key first: each pass sorts (key, position)
+        # pairs, which are all distinct, so even an unstable sort yields the
+        # stable order, and the passes compose into the lexicographic one.
+        # The TPU compiler builds these two-key sorts several times faster
+        # than one stable comparator over every key.
+        idx = rows
+        for n in reversed(list(names)):
             col = self.columns[n]
-            keys.append(jnp.where(bit, col, _max_key(col.dtype)))
-        # Most-significant key: invalid rows sink last even if a valid row
-        # happens to carry the max key value.
-        keys.append((~bit).astype(jnp.int32))
-        idx = jnp.lexsort(tuple(keys))
+            key = jnp.where(bit, col, _max_key(col.dtype))[idx]
+            _, order = jax.lax.sort((key, rows), num_keys=2, is_stable=False)
+            idx = idx[order]
+        # Most significant: invalid rows sink last even if a valid row
+        # happens to carry the max key value; (invalid, position) is one
+        # distinct int32 key.
+        last = jnp.where(bit[idx], 0, cap) + rows
+        _, idx = jax.lax.sort((last, idx), num_keys=1, is_stable=False)
         cols = {k: v[idx] for k, v in self.columns.items()}
         return ColumnarTable(cols, _bs.first_n(self.count, self.capacity),
                              self.count, self.capacity)

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.cohort import Cohort
-from repro.core.columnar import ColumnarTable, is_null
+from repro.core.columnar import ColumnarTable, cumsum, is_null
 from repro.core.events import Category
 
 __all__ = ["FeatureDriver", "TokenizerSpec"]
@@ -127,7 +127,7 @@ class FeatureDriver:
         # position within patient = rank among valid rows of the same patient
         seg = jnp.where(ok, pid, P)
         one = ok.astype(jnp.int32)
-        cum = jnp.cumsum(one) - one  # exclusive prefix count of valid rows
+        cum = cumsum(one) - one  # exclusive prefix count of valid rows
         # min of exclusive-cumsum within a segment = count before segment start
         big = jnp.int32(1 << 30)
         seg_start_count = jnp.full((P + 1,), big, jnp.int32).at[seg].min(cum, mode="drop")

@@ -28,7 +28,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import bitset as _bs
-from repro.core.columnar import ColumnarTable, NULL_FLOAT, NULL_INT, is_null
+from repro.core.columnar import (ColumnarTable, NULL_FLOAT, NULL_INT, cumsum,
+                                 is_null)
 from repro.core.schema import JoinEdge, StarSchema
 
 __all__ = [
@@ -205,7 +206,7 @@ def expand_join(
     # still emit one row with null right attributes.
     cnt = jnp.where(l_valid & ~is_null(lk), stop - start, 0)
     out_cnt = jnp.where(l_valid, jnp.maximum(cnt, 1), 0)
-    offs = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(out_cnt).astype(jnp.int32)])
+    offs = jnp.concatenate([jnp.zeros((1,), jnp.int32), cumsum(out_cnt).astype(jnp.int32)])
     total = offs[-1]
 
     j = jnp.arange(out_capacity, dtype=jnp.int32)

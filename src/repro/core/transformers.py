@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.columnar import ColumnarTable, NULL_INT, is_null
+from repro.core.columnar import ColumnarTable, NULL_INT, cumsum, is_null
 from repro.core.events import Category, make_events, sort_events
 from repro.core.metadata import OperationLog
 
@@ -154,7 +154,7 @@ def exposures(
     chained = same_group & (start - prev_start <= purview_days)
     new_exposure = evv & ~chained
     # exposure id per row (0-based); invalid rows ride along harmlessly
-    eid = jnp.cumsum(new_exposure.astype(jnp.int32)) - 1
+    eid = cumsum(new_exposure.astype(jnp.int32)) - 1
     eid = jnp.clip(eid, 0, cap - 1)
 
     first = _seg_min(start, eid, cap, evv)
@@ -194,8 +194,6 @@ def exposures_sharded(
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.pipeline import compat_shard_map
-
     n = mesh.shape[axis_name]
     # word-aligned shard blocks: the packed validity words split across the
     # mesh axis only when every shard's row block is a multiple of 32
@@ -208,9 +206,9 @@ def exposures_sharded(
         out = exposures(local, n_patients, **kw)
         return dict(out.columns), out.valid
 
-    fn = compat_shard_map(
-        body, mesh, in_specs=(P(axis_name), P(axis_name)),
-        out_specs=(P(axis_name), P(axis_name)),
+    fn = jax.shard_map(
+        body, mesh=mesh, in_specs=(P(axis_name), P(axis_name)),
+        out_specs=(P(axis_name), P(axis_name)), check_vma=False,
     )
     cols, valid = fn(dict(t.columns), t.valid)
     return ColumnarTable.from_columns(cols, valid=valid)

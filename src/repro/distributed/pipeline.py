@@ -23,8 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-__all__ = ["gpipe", "pipeline_transformer", "compat_shard_map",
-           "execute_plan_sharded", "pad_tables_for_mesh"]
+__all__ = ["gpipe", "pipeline_transformer", "execute_plan_sharded",
+           "pad_tables_for_mesh", "place_tables_on_mesh"]
 
 
 def pad_tables_for_mesh(tables, n_shards: int):
@@ -41,16 +41,22 @@ def pad_tables_for_mesh(tables, n_shards: int):
     return out
 
 
-def compat_shard_map(f: Callable, mesh: Mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions (>=0.6 top-level with check_vma;
-    older releases only ship ``jax.experimental.shard_map`` with check_rep)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
+def place_tables_on_mesh(tables, mesh: Mesh, axis_name: str = "data"):
+    """Pad tables to the mesh word quantum and shard their rows over
+    ``axis_name``: every column and the packed validity words split into
+    contiguous per-device blocks (``NamedSharding(mesh, P(axis))``), the
+    same layout the ``shard_map`` bodies read, so a sharded program takes
+    them without a reshard.  Scalar counts are replicated.  Arrays already
+    in that layout are not copied."""
+    from jax.sharding import NamedSharding
 
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    rows = NamedSharding(mesh, P(axis_name))
+    whole = NamedSharding(mesh, P())
+    tables = pad_tables_for_mesh(tables, mesh.shape[axis_name])
+    # leaf-wise: ColumnarTable's pytree round-trip keeps validity packed
+    return {k: jax.tree.map(
+                lambda x: jax.device_put(x, rows if jnp.ndim(x) else whole), t)
+            for k, t in tables.items()}
 
 
 def gpipe(stage_fn: Callable, mesh: Mesh, n_stages: int, axis_name: str = "pipe"):
@@ -166,7 +172,8 @@ def execute_plan_sharded(plan, tables, n_patients: int, mesh: Mesh,
     from repro.study.plan import COHORT_OPS, TABLE_OPS
 
     n = mesh.shape[axis_name]
-    env = pad_tables_for_mesh({src: tables[src] for src in plan.sources()}, n)
+    env = place_tables_on_mesh({src: tables[src] for src in plan.sources()},
+                               mesh, axis_name)
     cols_in = {s: dict(t.columns) for s, t in env.items()}
     valid_in = {s: t.valid for s, t in env.items()}
 
@@ -223,10 +230,10 @@ def execute_plan_sharded(plan, tables, n_patients: int, mesh: Mesh,
             s_out = jax.lax.psum(stats, axis_name) if stats else {}
             return t_out, b_out, c_out, s_out
 
-        return jax.jit(compat_shard_map(
-            body, mesh,
+        return jax.jit(jax.shard_map(
+            body, mesh=mesh,
             in_specs=(P(axis_name), P(axis_name)),
-            out_specs=(P(axis_name), P(), P(), P()),
+            out_specs=(P(axis_name), P(), P(), P()), check_vma=False,
         ))
 
     fn = cached_executable(key, build)

@@ -6,8 +6,9 @@ reduction halves HBM traffic vs. two XLA passes — on multi-million-patient
 universes (SNDS: 66M patients -> 2M words) the op is bandwidth-bound, so this
 is a straight 2x.
 
-Grid blocks are independent; per-block partial popcounts are summed by the
-wrapper (one tiny reduction).
+Words stream as lane-dense ``(words/128, 128)`` blocks; each grid block
+emits an ``(8, 128)`` tile of partial popcounts, which the wrapper sums (one
+tiny reduction).
 """
 from __future__ import annotations
 
@@ -16,7 +17,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_BLOCK = 1024
+from repro.kernels import LANES, default_interpret
+
+_QUANTUM = 8 * LANES               # words in one (8, 128) tile
+DEFAULT_BLOCK = 8 * _QUANTUM       # words per grid block
 
 OPS = {"and": 0, "or": 1, "andnot": 2, "xor": 3}
 
@@ -34,46 +38,44 @@ def _make_kernel(op: int):
         else:
             r = a ^ b
         out_ref[...] = r
-        pc_ref[0] = jax.lax.population_count(r).astype(jnp.int32).sum()
+        pc = jax.lax.population_count(r).astype(jnp.int32)
+        pc_ref[...] = pc.reshape(-1, 8, LANES).sum(axis=0)
 
     return _kernel
 
 
-def bitset_op_popcount(a: jax.Array, b: jax.Array, op: str, block: int = DEFAULT_BLOCK,
+def bitset_op_popcount(a: jax.Array, b: jax.Array, op: str,
+                       block: int = DEFAULT_BLOCK,
                        interpret: bool | None = None):
-    """Fused ``(a OP b, popcount(a OP b) per block)``.
+    """Fused ``(a OP b, partial popcounts)``.
 
-    Ragged tails are zero-padded to the block quantum (zero words contribute
-    no population, and every OPS entry maps 0 OP 0 -> 0, so padded words
-    never leak into counts); the padded tail is returned — callers slice.
-    ``interpret`` defaults by backend (interpret mode off-TPU).
+    Ragged tails are zero-padded to the block quantum (``block`` words,
+    rounded up to 1,024; zero words contribute no population, and every OPS
+    entry maps 0 OP 0 -> 0, so padded words never leak into counts); the
+    padded tail is returned — callers slice.  The partial popcounts sum to
+    the total.  ``interpret`` defaults by backend (interpret mode off-TPU).
     """
-    from repro.kernels import default_interpret
-
     interpret = default_interpret() if interpret is None else interpret
     n = a.shape[0]
     if n == 0:
         return jnp.zeros((0,), a.dtype), jnp.zeros((0,), jnp.int32)
-    pad = (-n) % block
-    if pad:
-        a = jnp.concatenate([a, jnp.zeros((pad,), a.dtype)])
-        b = jnp.concatenate([b, jnp.zeros((pad,), b.dtype)])
-        n += pad
-    grid = (n // block,)
-    return pl.pallas_call(
+    block = max(1, -(-int(block) // _QUANTUM)) * _QUANTUM
+    n_pad = -(-n // block) * block
+    a = jnp.pad(a, (0, n_pad - n)).reshape(-1, LANES)
+    b = jnp.pad(b, (0, n_pad - n)).reshape(-1, LANES)
+    rows = block // LANES
+    words, pc = pl.pallas_call(
         _make_kernel(OPS[op]),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block,), lambda g: (g,)),
-            pl.BlockSpec((block,), lambda g: (g,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block,), lambda g: (g,)),
-            pl.BlockSpec((1,), lambda g: (g,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n,), a.dtype),
-            jax.ShapeDtypeStruct((grid[0],), jnp.int32),
-        ],
+        grid=(n_pad // block,),
+        in_specs=[pl.BlockSpec((rows, LANES), lambda g: (g, 0)),
+                  pl.BlockSpec((rows, LANES), lambda g: (g, 0))],
+        out_specs=[pl.BlockSpec((rows, LANES), lambda g: (g, 0)),
+                   pl.BlockSpec((8, LANES), lambda g: (g, 0))],
+        out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype),
+                   jax.ShapeDtypeStruct((n_pad // block * 8, LANES),
+                                        jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(a, b)
+    return words.reshape(-1), pc.reshape(-1)

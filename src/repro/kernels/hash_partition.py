@@ -12,10 +12,13 @@ is one XLA gather in the wrapper, fed by (dest, rank, offsets).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import default_interpret
 
 DEFAULT_BLOCK = 512
 _MUL = 0x9E3779B1
@@ -42,16 +45,18 @@ def _kernel(keys_ref, valid_ref, dest_ref, rank_ref, hist_ref, *, n_dest: int):
 
 
 def hash_partition_plan(keys: jax.Array, valid: jax.Array, n_dest: int,
-                        block: int = DEFAULT_BLOCK, interpret: bool = True):
+                        block: int = DEFAULT_BLOCK,
+                        interpret: bool | None = None):
     """Per-row (dest, in-block rank) + per-block histograms.
 
     Returns ``(dest (N,), rank (N,), hist (n_blocks, n_dest))``.
-    ``N % block == 0`` (wrapper pads with invalid rows).
+    ``N % block == 0`` (wrapper pads with invalid rows).  ``interpret``
+    defaults by backend (interpret mode off-TPU).
     """
+    interpret = default_interpret() if interpret is None else interpret
     n = keys.shape[0]
     assert n % block == 0, (n, block)
     grid = (n // block,)
-    import functools
     return pl.pallas_call(
         functools.partial(_kernel, n_dest=n_dest),
         grid=grid,

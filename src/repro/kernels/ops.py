@@ -41,38 +41,41 @@ def _pad_to(x: jax.Array, mult: int, fill=0):
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def filter_compact(vals: jax.Array, mask: jax.Array, block: int = 256,
+def filter_compact(vals: jax.Array, mask: jax.Array,
+                   block: int = _fc.DEFAULT_BLOCK,
                    interpret: bool | None = None):
     """Compact ``vals[mask]`` to the front; returns (vals_out, count).
 
     ``mask`` is a ``(n,) bool`` row mask or — the bitset-native hot path —
     the packed ``(ceil(n/32),) uint32`` keep-mask (``ColumnarTable.valid`` /
-    predicate-kernel output; searchsorted over the per-block popcount
-    cumsums drives the stitch either way, but the packed form streams the
-    keep mask at 1 bit/row).  Kernel does block-local compaction; the
-    cross-block stitch is a single gather driven by cumsum of per-block
-    counts.
+    predicate-kernel output; a bool mask is packed at the boundary, so the
+    keep mask always streams at 1 bit/row).  The kernel compacts each
+    128-row segment; the cross-segment stitch is a single gather driven by
+    the cumsum of per-segment counts.
     """
     interpret = default_interpret() if interpret is None else interpret
     n = vals.shape[0]
     if n == 0:
         return vals, jnp.int32(0)
-    vp = _pad_to(vals, block)
     if getattr(mask, "dtype", None) == jnp.uint32:
-        wp = _pad_to(mask, block // 32)      # zero words: padded rows dropped
-        blocks, counts = _fc.filter_compact_bits_blocks(
-            vp, wp, block=block, interpret=interpret)
+        segs, counts = _fc.filter_compact_bits_blocks(
+            vals, mask, block=block, interpret=interpret)
     else:
-        mp = _pad_to(mask.astype(bool), block, fill=False)
-        blocks, counts = _fc.filter_compact_blocks(vp, mp, block=block, interpret=interpret)
-    offs = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(counts).astype(jnp.int32)])
+        segs, counts = _fc.filter_compact_blocks(
+            vals, mask, block=block, interpret=interpret)
+    from repro.core.columnar import cumsum
+
+    seg = _fc.SEGMENT
+    offs = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                            cumsum(counts).astype(jnp.int32)])
     total = offs[-1]
-    pos = jnp.arange(vp.shape[0], dtype=jnp.int32)
-    blk = jnp.clip(jnp.searchsorted(offs, pos, side="right") - 1, 0, counts.shape[0] - 1)
-    src = blk * block + (pos - offs[blk])
-    out = jnp.where(pos < total, blocks[jnp.clip(src, 0, vp.shape[0] - 1)],
+    pos = jnp.arange(n, dtype=jnp.int32)
+    blk = jnp.clip(jnp.searchsorted(offs, pos, side="right") - 1, 0,
+                   counts.shape[0] - 1)
+    src = blk * seg + (pos - offs[blk])
+    out = jnp.where(pos < total, segs[jnp.clip(src, 0, segs.shape[0] - 1)],
                     jnp.asarray(0, vals.dtype))
-    return out[:n], jnp.minimum(total, n)
+    return out, jnp.minimum(total, n)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -88,8 +91,8 @@ def segmented_scan(flags: jax.Array, vals: jax.Array, block: int = 512,
 
 
 @functools.partial(jax.jit, static_argnames=("op", "block", "interpret"))
-def bitset_op(a: jax.Array, b: jax.Array, op: str, block: int = 1024,
-              interpret: bool | None = None):
+def bitset_op(a: jax.Array, b: jax.Array, op: str,
+              block: int = _bo.DEFAULT_BLOCK, interpret: bool | None = None):
     """Fused bitwise op + total popcount; returns (words, count)."""
     interpret = default_interpret() if interpret is None else interpret
     n = a.shape[0]

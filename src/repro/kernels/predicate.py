@@ -7,12 +7,15 @@ per column reference plus a materialized bool column (1 byte/row) that every
 consumer re-reads.  This module compiles the serialized Expr tree into ONE
 Pallas kernel:
 
-  * one grid pass over the projected columns — every leaf op (comparisons,
-    arithmetic, ``isin`` via sorted-membership rank compares, sentinel null
-    tests, ``&``/``|``/``~``) evaluates entirely in VMEM;
+  * one grid pass over the projected columns, streamed as lane-dense
+    ``(rows/128, 128)`` tiles — every leaf op (comparisons, arithmetic,
+    ``isin`` via an SMEM whitelist scan, sentinel null tests,
+    ``&``/``|``/``~``) evaluates entirely in VMEM;
   * the output is a **packed uint32 bitset** (1 bit/row, 8x smaller than a
-    bool column) plus per-block popcounts: the mask pass itself never writes
-    a bool column, and the words use the shared ``core.bitset`` layout.
+    bool column): each tile's row mask is packed in VMEM
+    (``kernels.pack_tile``) and ANDed with the packed input validity, so
+    the mask pass never writes a bool column, and the words use the shared
+    ``core.bitset`` layout.
     Since the bitset-native validity redesign, ``ColumnarTable.valid`` IS
     this packed form, so the kernel's output becomes the downstream table's
     validity verbatim — no unpack hop — and both the input validity and the
@@ -21,17 +24,14 @@ Pallas kernel:
 
 Codegen is trace-time: ``compile_predicate`` walks the hashable param tree
 (``expr.Expr.to_param`` form — the exact object plan nodes carry) and emits a
-closure of jnp ops; ``pallas_call`` then lowers that closure per block.  The
-``isin`` whitelists are static plan params, so they are sorted host-side and
-streamed to every block; membership is the two monotone rank reductions
-``rank(<= x) > rank(< x)`` — broadcast compares + sums, the TPU-native
-formulation (no gather), exactly equivalent to sorted-array binary search.
+closure of jnp ops; ``pallas_call`` then lowers that closure per tile.  The
+``isin`` whitelists are static plan params, handed to the kernel as SMEM
+operands; membership ORs one scalar-vector compare per whitelist value.
 
 **Hoisted literals are kernel operands** (the normalized-plan path): a
 ``("hlit", slot)`` leaf becomes a ``(1,)`` SMEM scalar parameter and a
-``("hisin", x, slot, n, isfloat)`` whitelist becomes a sorted,
-lane-padded VMEM vector operand staged *inside* the jit (``jnp.sort`` +
-max-duplicate tail, so padding never adds members).  The compiled kernel is
+``("hisin", x, slot, n, isfloat)`` whitelist an ``(n,)`` SMEM operand
+staged *inside* the jit.  The compiled kernel is
 therefore value-generic: two tenants' queries differing only in literals
 share one executable, and ``normalize()`` no longer demotes hoisted pallas
 predicates to the jnp engine (oversized whitelists and non-boolean roots
@@ -52,20 +52,20 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu  # noqa: F401 (TPU lowering)
 
-from repro.kernels import default_interpret
+from repro.kernels import (BLOCK_QUANTUM, LANES, default_interpret,
+                           pack_tile, round_block)
 
 __all__ = [
     "DEFAULT_BLOCK", "MAX_ISIN_VALUES", "PREDICATE_ENGINES", "compilable",
-    "compile_predicate", "default_interpret", "isin_vmem_bytes",
+    "compile_predicate", "default_interpret", "isin_smem_bytes",
     "predicate_bitset", "resolve_engine",
 ]
 
-DEFAULT_BLOCK = 1024           # rows per grid block; must be a multiple of 32
+DEFAULT_BLOCK = BLOCK_QUANTUM  # rows per grid block
 
-# sorted-membership is a (block x whitelist) broadcast in VMEM: at the
-# default block, 1024 values ~ 4 MB of intermediate — comfortably resident;
-# bigger whitelists fall back to the jnp engine instead of risking VMEM
-# exhaustion on a real TPU (interpret-mode CI would never catch it)
+# an isin whitelist is an SMEM operand scanned once per tile (one compare per
+# value and row); past this size the kernel's SMEM and per-row cost grow with
+# the list, so bigger whitelists stay on the jnp engine
 MAX_ISIN_VALUES = 1024
 
 # mirrors columnar.NULL_INT (kernels stay import-light: no repro.core deps,
@@ -81,9 +81,6 @@ _ARITH = {"+": _op.add, "-": _op.sub, "*": _op.mul,
 # must be one of these (interior arithmetic is unrestricted)
 _BOOL_TAGS = frozenset({"cmp", "bool", "not", "isin", "hisin",
                         "isnull", "notnull"})
-
-# lane quantum the sorted whitelists are tail-padded to (static AND hoisted)
-_ISIN_PAD = 8
 
 # ---------------------------------------------------------------------------
 # engine selection
@@ -127,18 +124,12 @@ def _isin_sizes(p, out: list) -> None:
         _isin_sizes(x, out)
 
 
-def isin_vmem_bytes(n_values: int, block: int = DEFAULT_BLOCK) -> int:
-    """VMEM bytes the in-kernel sorted-membership broadcast needs for one
-    ``isin`` whitelist of ``n_values`` entries: the (block x whitelist)
-    comparison intermediate plus the resident operand, int32 lanes, with the
-    whitelist tail-padded to the ``_ISIN_PAD`` lane quantum (the padded form
-    is what actually crosses into VMEM — static tables and hoisted operands
-    alike).  The static analyzer quotes this in its engine-feasibility
-    diagnostics so an oversized whitelist comes with the budget it would
-    blow."""
-    n = max(int(n_values), 1)
-    n_pad = n + (-n) % _ISIN_PAD
-    return 4 * (block * n_pad + n_pad)
+def isin_smem_bytes(n_values: int) -> int:
+    """SMEM bytes one ``isin`` whitelist of ``n_values`` 32-bit entries
+    occupies as a kernel operand.  The static analyzer quotes this in its
+    engine-feasibility diagnostics so an oversized whitelist comes with the
+    budget it would blow."""
+    return 4 * max(int(n_values), 1)
 
 
 def compilable(expr_param) -> bool:
@@ -146,13 +137,12 @@ def compilable(expr_param) -> bool:
 
       * the root must be boolean-valued (packing bits of an arithmetic value
         would be meaningless), and
-      * every ``isin``/``hisin`` whitelist must fit the VMEM membership
-        budget (``MAX_ISIN_VALUES``; larger lists would blow the in-kernel
-        broadcast on a real TPU).  Hoisted whitelists count their structural
+      * every ``isin``/``hisin`` whitelist must fit the membership budget
+        (``MAX_ISIN_VALUES``).  Hoisted whitelists count their structural
         size ``n`` — the operand carries exactly that many values.
 
     Hoisted slot refs (``hlit``/``hisin``) are kernel *operands* — SMEM
-    scalars and sorted VMEM vectors — so normalized plans compile too.
+    scalars and SMEM whitelists — so normalized plans compile too.
     Non-compilable exprs stay on the jnp engine (``assign_engines`` stamps
     them back; the executor double-checks; ``normalize`` demotes hoisted
     pallas nodes only when this predicate says no)."""
@@ -173,20 +163,16 @@ def _is_null(v: jax.Array) -> jax.Array:
     return v == jnp.asarray(_NULL_INT, v.dtype)
 
 
-def _sorted_member(x: jax.Array, tbl: jax.Array) -> jax.Array:
-    """Sorted-membership: x ∈ tbl iff rank(tbl <= x) > rank(tbl < x).
+def _member(x: jax.Array, tbl, n: int) -> jax.Array:
+    """x ∈ tbl for an SMEM whitelist ref of ``n`` values: one scalar-vector
+    compare per value, ORed into an int32 accumulator (the TPU compiler
+    carries no bool vectors through a loop).  NaN probes and NaN entries
+    never compare equal -> non-member, matching ``jnp.isin``."""
+    def body(k, acc):
+        return acc | (x == tbl[k]).astype(jnp.int32)
 
-    Two monotone rank reductions over the sorted whitelist — broadcast
-    compares + row sums, all VPU work in VMEM (binary search without the
-    gathers TPUs lack).  NaN probes compare false both ways -> non-member,
-    matching ``jnp.isin``.
-    """
-    rd = jnp.promote_types(x.dtype, tbl.dtype)
-    xb = x.astype(rd)[:, None]
-    tb = tbl.astype(rd)[None, :]
-    le = (tb <= xb).sum(axis=1)
-    lt = (tb < xb).sum(axis=1)
-    return le > lt
+    return jax.lax.fori_loop(0, n, body, jnp.zeros(jnp.shape(x), jnp.int32),
+                             unroll=n <= 8) != 0
 
 
 def compile_predicate(expr_param: Tuple):
@@ -194,14 +180,13 @@ def compile_predicate(expr_param: Tuple):
     ``(columns, isin_tables, eval_fn, lit_slots, vec_slots)``.
 
     ``columns`` is the ordered tuple of column operands (the kernel's
-    projected inputs); ``isin_tables`` holds one sorted (tail-padded with its
-    own max, so padding can never match) numpy whitelist per ``isin`` leaf;
-    ``lit_slots`` is the ordered tuple of ``hlit`` slot ids the expr reads
-    (each becomes an SMEM scalar parameter) and ``vec_slots`` the ordered
-    ``(slot, n, isfloat)`` triples of its ``hisin`` leaves (each a sorted
-    VMEM vector operand).  ``eval_fn(env, tables, lits, vecs)`` maps
-    {column: block array} + table blocks + {slot: scalar} + {slot: sorted
-    operand} to the boolean mask block — pure jnp, traceable inside a Pallas
+    projected inputs); ``isin_tables`` holds one numpy whitelist per ``isin``
+    leaf; ``lit_slots`` is the ordered tuple of ``hlit`` slot ids the expr
+    reads (each becomes an SMEM scalar parameter) and ``vec_slots`` the
+    ordered ``(slot, n, isfloat)`` triples of its ``hisin`` leaves (each an
+    SMEM whitelist operand).  ``eval_fn(env, tables, lits, vecs)`` maps
+    {column: tile array} + whitelist refs + {slot: scalar} + {slot:
+    whitelist ref} to the boolean mask tile — traceable inside a Pallas
     kernel body.
     """
     columns: List[str] = []
@@ -257,14 +242,10 @@ def compile_predicate(expr_param: Tuple):
                     jnp.shape(jnp.asarray(x(env, tbls, lits, vecs))), bool)
             dt = np.float32 if any(isinstance(c, float) for c in vals) \
                 else np.int32
-            tbl = np.sort(np.asarray(vals, dt))
-            pad = (-tbl.size) % _ISIN_PAD
-            if pad:        # lane-align; max-duplicate padding never matches new values
-                tbl = np.concatenate([tbl, np.full(pad, tbl[-1], dt)])
             ti = len(tables)
-            tables.append(tbl)
-            return lambda env, tbls, lits, vecs: _sorted_member(
-                jnp.asarray(x(env, tbls, lits, vecs)), tbls[ti])
+            tables.append(np.asarray(vals, dt))
+            return lambda env, tbls, lits, vecs: _member(
+                jnp.asarray(x(env, tbls, lits, vecs)), tbls[ti], len(vals))
         if tag == "hisin":
             x = walk(p[1])
             slot, n, isfloat = int(p[2]), int(p[3]), bool(p[4])
@@ -273,8 +254,8 @@ def compile_predicate(expr_param: Tuple):
                     jnp.shape(jnp.asarray(x(env, tbls, lits, vecs))), bool)
             if slot not in [s for s, _, _ in vec_slots]:
                 vec_slots.append((slot, n, isfloat))
-            return lambda env, tbls, lits, vecs: _sorted_member(
-                jnp.asarray(x(env, tbls, lits, vecs)), vecs[slot])
+            return lambda env, tbls, lits, vecs: _member(
+                jnp.asarray(x(env, tbls, lits, vecs)), vecs[slot], n)
         raise ValueError(f"unknown Expr param tag {tag!r}")
 
     if expr_param[0] not in _BOOL_TAGS:
@@ -293,8 +274,9 @@ def _make_kernel(eval_fn: Callable, names: Sequence[str], n_tables: int,
                  vec_slot_ids: Sequence[int], lit_slot_ids: Sequence[int],
                  lit_bool: Sequence[bool]):
     """Kernel ref order: [cols...] [static isin tables...] [hoisted isin
-    vectors...] [hoisted lit SMEM scalars...] [packed valid] | [words, pc].
-    Bool lits are staged as int32 (SMEM-safe) and cast back here."""
+    vectors...] [hoisted lit scalars...] [packed valid] | [words].  Columns
+    are ``(block/128, 128)`` VMEM blocks, whitelists and literals SMEM
+    refs.  Bool lits are staged as int32 (SMEM-safe) and cast back here."""
     def _kernel(*refs):
         k = len(names)
         col_refs = refs[:k]
@@ -303,24 +285,22 @@ def _make_kernel(eval_fn: Callable, names: Sequence[str], n_tables: int,
         vec_refs = refs[k:k + len(vec_slot_ids)]
         k += len(vec_slot_ids)
         lit_refs = refs[k:k + len(lit_slot_ids)]
-        valid_ref = refs[k + len(lit_slot_ids)]
-        words_ref, pc_ref = refs[-2:]
+        valid_ref, words_ref = refs[-2:]
 
-        from repro.kernels import unpack_words_block
-
-        env = {nm: r[...] for nm, r in zip(names, col_refs)}
-        tbls = [r[...] for r in tbl_refs]
-        vecs = {s: r[...] for s, r in zip(vec_slot_ids, vec_refs)}
+        vecs = dict(zip(vec_slot_ids, vec_refs))
         lits = {s: (r[0] != 0 if b else r[0])
                 for s, b, r in zip(lit_slot_ids, lit_bool, lit_refs)}
-        # validity arrives PACKED (1 bit/row of HBM); expand in VMEM only
-        m = eval_fn(env, tbls, lits, vecs) & unpack_words_block(valid_ref[...])
-
-        B = m.shape[0]
-        lanes = jax.lax.broadcasted_iota(jnp.uint32, (B // 32, 32), 1)
-        bits = m.reshape(B // 32, 32).astype(jnp.uint32) << lanes
-        words_ref[...] = bits.sum(axis=1).astype(jnp.uint32)
-        pc_ref[0] = m.astype(jnp.int32).sum()
+        # validity arrives PACKED (1 bit/row of HBM) and is ANDed word-wise:
+        # the row mask of the expression is packed, never the validity
+        valid = valid_ref[...]
+        out = []
+        for t in range(valid.shape[0] // 4):         # static tile loop
+            rows = slice(LANES * t, LANES * (t + 1))
+            env = {nm: r[rows, :] for nm, r in zip(names, col_refs)}
+            m = jnp.broadcast_to(eval_fn(env, tbl_refs, lits, vecs),
+                                 (LANES, LANES))
+            out.append(pack_tile(m) & valid[4 * t:4 * t + 4, :])
+        words_ref[...] = jnp.concatenate(out, axis=0)
 
     return _kernel
 
@@ -330,9 +310,8 @@ def _stage_hoisted(lit_slots: Sequence[int],
                    params: Tuple[Dict[int, jax.Array], Dict[int, jax.Array]]):
     """Stage bound ``{slot: value}`` maps as kernel operands (traced — runs
     inside the jit): each ``hlit`` slot becomes a ``(1,)`` scalar (bools as
-    int32, SMEM has no bool lanes) and each ``hisin`` slot a sorted vector
-    tail-padded to the lane quantum with its own max (padding duplicates an
-    existing member, so membership is unchanged)."""
+    int32, SMEM has no bool lanes) and each ``hisin`` slot its ``(n,)``
+    whitelist."""
     b_lits, b_vecs = params
     lit_ops, lit_bool = [], []
     for slot in lit_slots:
@@ -347,11 +326,7 @@ def _stage_hoisted(lit_slots: Sequence[int],
         if v.shape != (n,):
             raise ValueError(f"hoisted whitelist slot {slot}: bound value "
                              f"has shape {v.shape}, expr expects ({n},)")
-        s = jnp.sort(v)
-        pad = (-n) % _ISIN_PAD
-        if pad:
-            s = jnp.concatenate([s, jnp.full((pad,), s[-1], s.dtype)])
-        vec_ops.append(s)
+        vec_ops.append(v)
     return lit_ops, tuple(lit_bool), vec_ops
 
 
@@ -363,17 +338,17 @@ def predicate_bitset_blocks(expr_param: Tuple, cols: Dict[str, jax.Array],
     ``valid_words`` bitset (``core.bitset`` layout — validity is streamed at
     1 bit/row, not a bool column).
 
-    Returns ``(words, popcounts)`` — the packed uint32 bitset (n/32 words)
-    and the per-block popcounts.  Column length must be a multiple of
-    ``block`` (``predicate_bitset`` pads); ``block`` a multiple of 32;
-    ``valid_words`` holds exactly n/32 words.  ``params`` is the bound
-    ``(lits, vecs)`` pair backing any hoisted slot refs in the expr.
+    Returns the packed uint32 bitset (n/32 words).  Column length must be a
+    multiple of ``block``, itself a multiple of ``BLOCK_QUANTUM`` = 32,768
+    rows (``predicate_bitset`` pads); ``valid_words`` holds exactly n/32
+    words.
+    ``params`` is the bound ``(lits, vecs)`` pair backing any hoisted slot
+    refs in the expr.
     """
     interpret = default_interpret() if interpret is None else interpret
-    assert block % 32 == 0, block
+    assert block % BLOCK_QUANTUM == 0, block
     n = valid_words.shape[0] * 32
     assert n % block == 0, (n, block)
-    grid = (n // block,)
     names, tables, eval_fn, lit_slots, vec_slots = compile_predicate(
         expr_param)
     missing = [nm for nm in names if nm not in cols]
@@ -381,33 +356,31 @@ def predicate_bitset_blocks(expr_param: Tuple, cols: Dict[str, jax.Array],
         raise KeyError(f"predicate reads absent column(s) {missing}")
     lit_ops, lit_bool, vec_ops = _stage_hoisted(lit_slots, vec_slots, params)
 
-    in_specs = [pl.BlockSpec((block,), lambda g: (g,)) for _ in names]
-    in_specs += [pl.BlockSpec((int(t.size),), lambda g: (0,)) for t in tables]
-    in_specs += [pl.BlockSpec((int(v.shape[0]),), lambda g: (0,))
-                 for v in vec_ops]
-    # scalar literal params live in SMEM — one (1,) ref per slot, read
-    # whole (no index_map: scalars are grid-invariant)
-    in_specs += [pl.BlockSpec(memory_space=pltpu.SMEM) for _ in lit_ops]
-    in_specs += [pl.BlockSpec((block // 32,), lambda g: (g,))]
-    operands = ([cols[nm] for nm in names]
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    in_specs = [pl.BlockSpec((block // LANES, LANES), lambda g: (g, 0))
+                for _ in names]
+    # whitelists and scalar literals are grid-invariant SMEM operands
+    in_specs += [smem] * (len(tables) + len(vec_ops) + len(lit_ops))
+    words_spec = pl.BlockSpec((block // 32 // LANES, LANES), lambda g: (g, 0))
+    in_specs += [words_spec]
+    operands = ([cols[nm].reshape(-1, LANES) for nm in names]
                 + [jnp.asarray(t) for t in tables]
                 + vec_ops + lit_ops
-                + [valid_words.astype(jnp.uint32)])
-    return pl.pallas_call(
+                + [jax.lax.bitcast_convert_type(
+                    valid_words.astype(jnp.uint32), jnp.int32
+                ).reshape(-1, LANES)])
+    words = pl.pallas_call(
         _make_kernel(eval_fn, names, len(tables),
                      [s for s, _, _ in vec_slots], lit_slots, lit_bool),
-        grid=grid,
+        grid=(n // block,),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((block // 32,), lambda g: (g,)),
-            pl.BlockSpec((1,), lambda g: (g,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n // 32,), jnp.uint32),
-            jax.ShapeDtypeStruct((grid[0],), jnp.int32),
-        ],
+        out_specs=words_spec,
+        out_shape=jax.ShapeDtypeStruct((n // 32 // LANES, LANES), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(*operands)
+    return jax.lax.bitcast_convert_type(words, jnp.uint32).reshape(-1)
 
 
 def _pad_to(x: jax.Array, mult: int, fill=0):
@@ -426,11 +399,13 @@ def _predicate_bitset_jit(columns: Dict[str, jax.Array], words: jax.Array,
                           interpret: Optional[bool], n: int):
     if n == 0:
         return jnp.zeros((0,), jnp.uint32), jnp.int32(0)
+    block = round_block(block)
     cols = {nm: _pad_to(c, block) for nm, c in columns.items()}
     wp = _pad_to(words, block // 32)
-    out, pc = predicate_bitset_blocks(expr_param, cols, wp, block=block,
-                                      interpret=interpret, params=params)
-    return out[: (n + 31) // 32], pc.sum().astype(jnp.int32)
+    out = predicate_bitset_blocks(expr_param, cols, wp, block=block,
+                                  interpret=interpret, params=params)
+    out = out[: (n + 31) // 32]
+    return out, jax.lax.population_count(out).sum(dtype=jnp.int32)
 
 
 def predicate_bitset(columns: Dict[str, jax.Array], valid: jax.Array, *,

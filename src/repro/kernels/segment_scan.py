@@ -24,8 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams (~0.5); accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from repro.kernels import default_interpret
 
 DEFAULT_BLOCK = 512
 _BIG = 2_000_000_000
@@ -87,11 +86,13 @@ def _kernel(flags_ref, vals_ref, omin_ref, omax_ref, ocnt_ref,
 
 
 def segmented_scan(flags: jax.Array, vals: jax.Array, block: int = DEFAULT_BLOCK,
-                   interpret: bool = True):
+                   interpret: bool | None = None):
     """Inclusive segmented (min, max, count) scan; `flags[i]` starts a run.
 
     Length must be a multiple of ``block`` (wrapper pads with flag=True).
+    ``interpret`` defaults by backend (interpret mode off-TPU).
     """
+    interpret = default_interpret() if interpret is None else interpret
     n = vals.shape[0]
     assert n % block == 0, (n, block)
     grid = (n // block,)
@@ -114,7 +115,7 @@ def segmented_scan(flags: jax.Array, vals: jax.Array, block: int = DEFAULT_BLOCK
         ],
         scratch_shapes=[pltpu.SMEM((4,), jnp.int32)],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),  # sequential: carry dependency
         ),
     )(flags.astype(jnp.int8), vals)

@@ -25,8 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams (~0.5); accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from repro.kernels import default_interpret
 
 DEFAULT_BQ = 128
 DEFAULT_BK = 128
@@ -106,10 +105,12 @@ def flash_swa_attention(
     kv_len: int | None = None,     # true (unpadded) KV length
     bq: int = DEFAULT_BQ,
     bk: int = DEFAULT_BK,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Blocked flash attention; see module docstring.  Sq, Skv must divide by
-    (bq, bk) — wrapper in ``ops.py`` pads and unpads."""
+    (bq, bk) — wrapper in ``ops.py`` pads and unpads.  ``interpret``
+    defaults by backend (interpret mode off-TPU)."""
+    interpret = default_interpret() if interpret is None else interpret
     B, Hq, Sq, D = q.shape
     _, Hkv, Skv, _ = k.shape
     assert Hq % Hkv == 0
@@ -150,7 +151,7 @@ def flash_swa_attention(
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

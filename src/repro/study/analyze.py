@@ -635,11 +635,11 @@ def _check_predicate(node, i: int, left: Optional[NodeFact], emit,
     # engine feasibility
     oversized = [s for _, s in wls if s > _pk.MAX_ISIN_VALUES]
     if oversized:
-        vmem = _pk.isin_vmem_bytes(max(oversized))
+        smem = _pk.isin_smem_bytes(max(oversized))
         emit("SP008", i, f"isin whitelist of {max(oversized)} values "
              f"exceeds the pallas membership budget "
-             f"({_pk.MAX_ISIN_VALUES}); the broadcast intermediate alone "
-             f"needs ~{vmem / 2**20:.1f} MiB of VMEM — the executor falls "
+             f"({_pk.MAX_ISIN_VALUES}); the whitelist alone needs "
+             f"{smem / 2**10:.1f} KiB of SMEM — the executor falls "
              "back to the jnp engine",
              hint="split the whitelist or pre-join a code dimension")
     if node.get("engine") == "pallas":
@@ -651,9 +651,8 @@ def _check_predicate(node, i: int, left: Optional[NodeFact], emit,
         if _has_concrete_literal(param) and _pk.compilable(param):
             emit("SP009", i, "pallas-stamped mask carries inline literals; "
                  "normalize() hoists them into traced slots that enter the "
-                 "kernel as operands (scalar literals via SMEM, sorted "
-                 "isin whitelists as padded VMEM vectors) — the node keeps "
-                 "the pallas engine when served",
+                 "kernel as operands (scalar literals and isin whitelists "
+                 "via SMEM) — the node keeps the pallas engine when served",
                  hint="structurally-equal plans with different literal "
                       "values share one compiled executable; only "
                       "kernel-infeasible stamps (SP008) demote to jnp")

@@ -98,7 +98,7 @@ def _sp007() -> Tuple[Plan, Dict[str, Any]]:
 
 
 def _sp008() -> Tuple[Plan, Dict[str, Any]]:
-    # an isin whitelist past the kernel's VMEM operand budget, force-stamped
+    # an isin whitelist past the kernel's SMEM operand budget, force-stamped
     # pallas (the optimizer would refuse the stamp): the one shape that
     # still demotes to jnp when served now that hoisted literals are
     # first-class kernel operands

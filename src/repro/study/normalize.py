@@ -27,8 +27,8 @@ canonical form where that no longer happens:
     serialize equally regardless of how they were built.
 
 Hoisted predicates keep the Pallas engine: the Expr->bitset codegen takes
-hoisted literals as kernel *operands* (SMEM scalars, sorted VMEM whitelist
-vectors), so a normalized plan gets cross-tenant compile sharing AND the
+hoisted literals as kernel *operands* (SMEM scalars and SMEM whitelists),
+so a normalized plan gets cross-tenant compile sharing AND the
 fused kernel.  Demotion to ``"jnp"`` is now the exception — it happens only
 when the hoisted form is not kernel-compilable (oversized ``isin``
 whitelist, non-boolean root), and ``NormalPlan.demoted`` records exactly

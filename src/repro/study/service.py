@@ -331,16 +331,19 @@ class CohortQueryService:
 
     # -- residency -----------------------------------------------------------
     def _load_tables(self, tables: Dict[str, ColumnarTable]) -> None:
-        if self.mesh is not None:
-            from repro.distributed.pipeline import pad_tables_for_mesh
-
-            tables = pad_tables_for_mesh(tables,
-                                         self.mesh.shape[self.axis_name])
         # loaded ONCE per table version: device residency is the service's
-        # contract — queries never re-upload sources (leaf-wise device_put:
-        # ColumnarTable's pytree round-trip re-packs validity on unflatten)
-        self._env = {k: jax.tree.map(jax.device_put, t)
-                     for k, t in tables.items()}
+        # contract — queries never re-upload or reshard sources.  With a mesh
+        # the rows shard over the patient axis the programs read them on
+        # (leaf-wise device_put: ColumnarTable's pytree round-trip re-packs
+        # validity on unflatten)
+        if self.mesh is not None:
+            from repro.distributed.pipeline import place_tables_on_mesh
+
+            self._env = place_tables_on_mesh(tables, self.mesh,
+                                             self.axis_name)
+        else:
+            self._env = {k: jax.tree.map(jax.device_put, t)
+                         for k, t in tables.items()}
         self.log.record(
             op="service:load_tables", inputs={},
             outputs={k: _Count(int(t.count)) for k, t in tables.items()},
@@ -591,7 +594,12 @@ class CohortQueryService:
         t0 = time.perf_counter()
         study = ticket.study
         peng_arg = self.config.predicate_engine
-        plan = study.optimized_plan(tables=self._env,
+        # a mesh plan keeps its exchanges and per-shard capacities: optimized
+        # for one shard, joins would only match rows that happen to share a
+        # device
+        n_shards = (self.mesh.shape[self.axis_name]
+                    if self.mesh is not None else 1)
+        plan = study.optimized_plan(tables=self._env, n_shards=n_shards,
                                     predicate_engine=peng_arg or "auto",
                                     engine=self.config.engine)
         # admission-time static analysis: error-level plans (unknown
@@ -599,8 +607,6 @@ class CohortQueryService:
         # mismatches) are rejected BEFORE they reach normalization or the
         # compile cache — a broken tenant plan must not cost a compile slot
         # or poison shared executables
-        n_shards = (self.mesh.shape[self.axis_name]
-                    if self.mesh is not None else 1)
         diags = _analyze_plan(plan, tables=self._env, n_shards=n_shards,
                               n_patients=study.n_patients)
         if any(d.severity == "error" for d in diags):
@@ -927,10 +933,9 @@ class CohortQueryService:
         cut nodes are those whose shard-local capacity is 32-aligned (the
         cached global words then split on shard row boundaries); the rest
         compute in place, uncached."""
-        from jax.sharding import PartitionSpec as P
+        from jax.sharding import NamedSharding, PartitionSpec as P
 
         from repro.core.bitset import count as _bits_count
-        from repro.distributed.pipeline import compat_shard_map
 
         plan = nplan.plan
         mesh, axis = self.mesh, self.axis_name
@@ -968,10 +973,10 @@ class CohortQueryService:
                     {i: vals[i].count for i in candidates},
                     {i: stats.get(i) for i in candidates})
 
-        probe_fn = compat_shard_map(
-            probe, mesh,
+        probe_fn = jax.shard_map(
+            probe, mesh=mesh,
             in_specs=(P(axis), P(axis), P(), P()),
-            out_specs=(P(axis), P(), P()))
+            out_specs=(P(axis), P(), P()), check_vma=False)
         cut_struct, cnt_struct, stats_struct = jax.eval_shape(
             probe_fn, cols_in, valid_in, lits, vecs)
 
@@ -984,7 +989,13 @@ class CohortQueryService:
 
         cut_ids = tuple(i for i in candidates if _eligible(i))
         cut_set = frozenset(cut_ids)
-        zeros = {i: _zeros_like_struct(cut_struct[i]) for i in cut_ids}
+        # miss placeholders live where the program reads cut tables: sharded
+        # over the patient axis, so a miss moves no bytes between devices
+        rows = NamedSharding(mesh, P(axis))
+        zeros = {i: jax.tree.map(
+                     lambda s: jnp.zeros(s.shape, s.dtype, device=rows),
+                     cut_struct[i])
+                 for i in cut_ids}
 
         def body(cols, valids, lits, vecs, cut_tabs, flags):
             local = {s: ColumnarTable(c, valids[s], _bits_count(valids[s]))
@@ -1037,10 +1048,10 @@ class CohortQueryService:
             s_out = jax.lax.psum(stats, axis) if stats else {}
             return t_out, b_out, c_out, s_out, cut_out
 
-        fn = jax.jit(compat_shard_map(
-            body, mesh,
+        fn = jax.jit(jax.shard_map(
+            body, mesh=mesh,
             in_specs=(P(axis), P(axis), P(), P(), P(axis), P()),
-            out_specs=(P(axis), P(), P(), P(), P(axis))))
+            out_specs=(P(axis), P(), P(), P(), P(axis)), check_vma=False))
         return _Program(fn=fn, cut_ids=cut_ids, zeros=zeros)
 
     # -- subgraph cache ------------------------------------------------------

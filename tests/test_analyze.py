@@ -17,7 +17,8 @@ import pytest
 
 import jax.numpy as jnp
 
-from _hyp import given, settings, st
+import hypothesis.strategies as st
+from hypothesis import given, settings
 from repro.core import DCIR_SCHEMA, drug_dispenses, medical_acts_dcir
 from repro.core.columnar import ColumnarTable
 from repro.data.synthetic import SyntheticConfig, generate_dcir
@@ -191,7 +192,7 @@ def test_normalize_keeps_hoisted_literals_on_pallas():
 
 
 def test_normalize_demotes_kernel_infeasible_stamp():
-    # force-stamp pallas onto an isin past the VMEM operand budget (the
+    # force-stamp pallas onto an isin past the SMEM operand budget (the
     # optimizer itself would stamp jnp) — the one case that still demotes
     from repro.kernels.predicate import MAX_ISIN_VALUES
     from repro.study.expr import as_param

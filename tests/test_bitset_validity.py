@@ -8,7 +8,7 @@ The table/cohort data model carries row validity as a packed uint32 bitset
     deterministic battery over every columnar op);
   * every *plan* op (mask, compact, join, slice_time, flow, stats battery)
     is bit-identical under bool-valid vs bitset-valid input tables, locally
-    and under ``compat_shard_map``;
+    and under ``jax.shard_map``;
   * the optimizer's ``eliminate_joins`` degrades a pruned-to-key lookup_join
     to an audit-only ``key_count`` without changing results;
   * executor-level no-unpack assertion: on the Pallas engines the
@@ -17,7 +17,8 @@ The table/cohort data model carries row validity as a packed uint32 bitset
   * the ">25 statistics" battery expands each cohort/table bitset ONCE per
     ``stats.compute`` (memoized unpack).
 """
-from _hyp import given, settings, st
+import hypothesis.strategies as st
+from hypothesis import given, settings
 import jax
 import jax.numpy as jnp
 import numpy as np

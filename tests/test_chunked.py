@@ -10,7 +10,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hyp import given, settings, st
+import hypothesis.strategies as st
+from hypothesis import given, settings
 
 from repro.core import DCIR_SCHEMA, drug_dispenses
 from repro.core.columnar import ColumnarTable

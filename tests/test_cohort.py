@@ -1,6 +1,7 @@
 """Cohort algebra tests: bitset <-> set homomorphism (hypothesis), flow
 flowcharts, description composition (paper Supplementary Out[6])."""
-from _hyp import given, settings, st
+import hypothesis.strategies as st
+from hypothesis import given, settings
 import jax.numpy as jnp
 import numpy as np
 import pytest

@@ -1,5 +1,6 @@
 """ColumnarTable unit + property tests (the Parquet-analogue invariants)."""
-from _hyp import given, settings, st
+import hypothesis.strategies as st
+from hypothesis import given, settings
 import jax.numpy as jnp
 import numpy as np
 import pytest

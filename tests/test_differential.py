@@ -11,13 +11,13 @@ evaluation paths of the same ``Expr`` must agree bit-for-bit on every table —
 
 Hypothesis generates random Expr trees over random ColumnarTables (mixed
 int32/float32 dtypes, NULL sentinels, NaNs, random validity, ragged
-non-block-multiple lengths); the deterministic battery keeps the same
-coverage alive on bare containers where hypothesis degrades to skips
-(tests/_hyp.py).
+non-block-multiple lengths); a deterministic battery pins the same
+coverage on fixed cases.
 """
 import numpy as np
 import pytest
-from _hyp import given, settings, st
+import hypothesis.strategies as st
+from hypothesis import given, settings
 
 import jax.numpy as jnp
 
@@ -141,7 +141,7 @@ def test_kernel_empty_table():
 
 
 def test_oversized_isin_falls_back_to_jnp():
-    """Whitelists past the VMEM membership budget are not kernel-compilable;
+    """Whitelists past the membership budget are not kernel-compilable;
     assign_engines stamps them back to jnp and execution still agrees."""
     from repro.kernels.predicate import MAX_ISIN_VALUES
 

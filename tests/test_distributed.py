@@ -6,18 +6,9 @@ import subprocess
 import sys
 import textwrap
 
-import jax
 import pytest
 
 SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
-
-# Seed-debt triage (see tests/test_models.py for the full note): the mesh
-# helpers these subprocesses import need jax.sharding.AxisType, absent from
-# the container's jax.  strict=False — they reactivate on a newer jax.
-jax_version_xfail = pytest.mark.xfail(
-    not hasattr(jax.sharding, "AxisType"), strict=False,
-    reason="seed debt: installed jax lacks jax.sharding.AxisType/"
-           "get_abstract_mesh required by the mesh stack")
 
 
 def run_subprocess(code: str) -> dict:
@@ -30,7 +21,6 @@ def run_subprocess(code: str) -> dict:
     return json.loads(out.stdout.strip().splitlines()[-1])
 
 
-@jax_version_xfail
 def test_distributed_flatten_matches_local():
     code = textwrap.dedent("""
         import json
@@ -62,7 +52,6 @@ def test_distributed_flatten_matches_local():
     assert r["pid_sum_local"] == r["pid_sum_dist"]
 
 
-@jax_version_xfail
 def test_exchange_partitions_by_key():
     """After exchange, every shard holds only keys that hash to it."""
     code = textwrap.dedent("""
@@ -103,7 +92,6 @@ def test_exchange_partitions_by_key():
     assert r["total_rows"] == 4096
 
 
-@jax_version_xfail
 def test_sharded_train_step_runs():
     """Reduced model, (2 data, 2 model) mesh: one sharded train step."""
     code = textwrap.dedent("""
@@ -150,7 +138,6 @@ def test_dryrun_artifacts_if_present():
     assert not bad, bad
 
 
-@jax_version_xfail
 def test_sharded_moe_matches_unsharded():
     """EP shard_map path == dense path numerically (same params, same batch).
 
@@ -177,7 +164,6 @@ def test_sharded_moe_matches_unsharded():
     assert abs(r["dense"] - r["ep"]) < 0.05, r
 
 
-@jax_version_xfail
 def test_sharded_forward_matches_unsharded_dense_arch():
     """SP constraints must not change numerics for a dense arch."""
     code = textwrap.dedent("""
@@ -200,7 +186,6 @@ def test_sharded_forward_matches_unsharded_dense_arch():
     assert abs(r["unsharded"] - r["sharded"]) < 0.02, r
 
 
-@jax_version_xfail
 def test_exposures_sharded_matches_local():
     """Patient-partitioned shard-local exposures == global exposures."""
     code = textwrap.dedent("""
@@ -234,3 +219,45 @@ def test_exposures_sharded_matches_local():
     r = run_subprocess(code)
     assert r["overflow"] == 0
     assert r["match"] and r["n"] > 0, r
+
+
+def test_sharded_service_matches_solo_on_four_devices():
+    """A 4-device mesh service plans each query for its shard count: the
+    exchanges stay in, so a flatten join matches rows that live on other
+    devices, and the rows land on the mesh the programs read them on."""
+    code = textwrap.dedent("""
+        import json
+        import jax, numpy as np
+        from jax.sharding import Mesh
+        from repro.core import DCIR_SCHEMA, drug_dispenses
+        from repro.data.synthetic import SyntheticConfig, generate_dcir
+        from repro.study import CohortQueryService, Study
+
+        n = 300
+        dcir = generate_dcir(SyntheticConfig(n_patients=n, seed=5))
+        q = (Study(n_patients=n).flatten(DCIR_SCHEMA)
+             .extract(drug_dispenses(codes=list(range(64))), name="drugs")
+             .patients("IR_BEN").cohort("base", "extract_patients")
+             .cohort("drugged", "drugs").cohort("final", "drugged & base"))
+        mesh = Mesh(np.asarray(jax.devices()[:4]), ("data",))
+        solo = q.run(dict(dcir))
+        svc = CohortQueryService(dict(dcir), mesh=mesh)
+        t = svc.submit(q)
+        svc.drain()
+        rows = lambda r: sorted(zip(*(r.events["drugs"].to_numpy()[c]
+                                      .tolist() for c in ("patient_id",
+                                                          "value", "start"))))
+        env = svc._env["ER_PRS"].columns["flow_id"]
+        print(json.dumps({
+            "status": t.status,
+            "same_rows": rows(t.result) == rows(solo),
+            "same_cohorts": all(np.array_equal(
+                np.asarray(t.result.cohorts[k].subjects),
+                np.asarray(solo.cohorts[k].subjects)) for k in solo.cohorts),
+            "resident_devices": len(env.sharding.device_set),
+        }))
+    """)
+    r = run_subprocess(code)
+    assert r["status"] == "done"
+    assert r["same_rows"] and r["same_cohorts"]
+    assert r["resident_devices"] == 4

@@ -5,7 +5,8 @@ acceptance criterion: dimension columns no extractor reads are dropped from
 the star scans before the first join, with identical end-to-end results)."""
 import numpy as np
 import pytest
-from _hyp import given, settings, st
+import hypothesis.strategies as st
+from hypothesis import given, settings
 
 from repro.core import DCIR_SCHEMA, PMSI_MCO_SCHEMA, drug_dispenses, \
     flatten_star, medical_acts_dcir

@@ -1,6 +1,7 @@
 """SCALPEL-Flattening tests: joins vs numpy oracles, temporal slicing
 equivalence, monitoring (no-loss) statistics."""
-from _hyp import given, settings, st
+import hypothesis.strategies as st
+from hypothesis import given, settings
 import jax.numpy as jnp
 import numpy as np
 import pytest

@@ -1,5 +1,6 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs pure-jnp refs."""
-from _hyp import given, settings, st
+import hypothesis.strategies as st
+from hypothesis import given, settings
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -125,11 +126,19 @@ def test_kernel_interpret_defaults_follow_backend():
     # callable without interpret= on any backend
     v = jnp.arange(64, dtype=jnp.int32)
     m = jnp.ones(64, bool)
-    out, cnt = fc.filter_compact_blocks(v, m, block=64)
-    assert int(cnt[0]) == 64 and (np.asarray(out) == np.asarray(v)).all()
+    out, cnt = fc.filter_compact_blocks(v, m, block=64)   # padded tail back
+    assert int(cnt[0]) == 64 and (np.asarray(out)[:64] == np.asarray(v)).all()
     w, p = bo.bitset_op_popcount(v.astype(jnp.uint32),
                                  v.astype(jnp.uint32), "and", block=64)
-    assert (np.asarray(w) == np.asarray(v)).all()
+    assert (np.asarray(w)[:64] == np.asarray(v)).all()
+    # the off-path kernels resolve interpret=None the same way
+    from repro.kernels import hash_partition as hp
+    from repro.kernels import segment_scan as ss
+
+    d, _, _ = hp.hash_partition_plan(v, m, 4, block=64)
+    assert d.shape == (64,)
+    mn, _, _ = ss.segmented_scan(m, v, block=64)
+    assert int(mn[-1]) == 63          # every row starts its own run
 
 
 # -- hash partition ---------------------------------------------------------------

@@ -10,28 +10,8 @@ from repro.configs.archs import ARCHS, LONG_CONTEXT_OK
 from repro.models import get_bundle, all_archs
 from repro.models import lm as LM
 
-# Seed-debt triage: the model/mesh stack targets a newer jax than the
-# container ships — jax.sharding.AxisType / get_abstract_mesh are absent, so
-# every forward pass dies in layers.py/mesh.py.  strict=False + the hasattr
-# condition: the day the jax toolchain catches up these run (and must pass)
-# again, while *new* regressions elsewhere stay loud.  Tracked in CHANGES.md
-# (PR 4) and ROADMAP "Seed state: seed tests failing".
-jax_version_xfail = pytest.mark.xfail(
-    not hasattr(jax.sharding, "AxisType"), strict=False,
-    reason="seed debt: installed jax lacks jax.sharding.AxisType/"
-           "get_abstract_mesh required by the model stack")
 
 KEY = jax.random.key(0)
-
-
-def _mesh_dependent_archs():
-    # seamless-m4t-medium (encoder-decoder frontend) never reaches the
-    # mesh-dependent sdpa path and passes on the container jax — keep it a
-    # HARD test so regressions there stay loud; every other arch needs the
-    # missing jax.sharding API and carries the conditional xfail.
-    return [a if a == "seamless-m4t-medium"
-            else pytest.param(a, marks=jax_version_xfail)
-            for a in all_archs()]
 
 
 def make_batch(cfg, B=2, S=32):
@@ -45,7 +25,7 @@ def make_batch(cfg, B=2, S=32):
     return batch
 
 
-@pytest.mark.parametrize("arch", _mesh_dependent_archs())
+@pytest.mark.parametrize("arch", all_archs())
 def test_arch_smoke_train_step(arch):
     b = get_bundle(arch, reduced=True)
     params = b.init(KEY)
@@ -57,7 +37,7 @@ def test_arch_smoke_train_step(arch):
     assert np.isfinite(gnorm) and gnorm > 0, arch
 
 
-@pytest.mark.parametrize("arch", _mesh_dependent_archs())
+@pytest.mark.parametrize("arch", all_archs())
 def test_arch_smoke_prefill_and_decode(arch):
     b = get_bundle(arch, reduced=True)
     params = b.init(KEY)
@@ -78,7 +58,6 @@ def test_arch_smoke_prefill_and_decode(arch):
     "llama3.2-3b", "h2o-danube-1.8b", "gemma3-12b", "recurrentgemma-2b",
     "xlstm-125m",
 ])
-@jax_version_xfail
 def test_decode_matches_parallel(arch):
     """Token-by-token decode with cache == parallel forward (ring buffers,
     recurrent states, GQA, mLSTM recurrent form)."""
@@ -100,7 +79,6 @@ def test_decode_matches_parallel(arch):
     assert maxerr < 0.05, (arch, maxerr)
 
 
-@jax_version_xfail
 def test_moe_routing_mass_conserved():
     """Top-k gate weights sum to 1 per token; padded experts get no mass."""
     from repro.models import layers as L
@@ -119,7 +97,6 @@ def test_moe_routing_mass_conserved():
     assert np.isfinite(np.asarray(y, np.float32)).all()
 
 
-@jax_version_xfail
 def test_vlm_image_positions_masked_in_loss():
     b = get_bundle("phi-3-vision-4.2b", reduced=True)
     cfg = b.cfg

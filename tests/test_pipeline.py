@@ -5,18 +5,8 @@ import subprocess
 import sys
 import textwrap
 
-import jax
-import pytest
 
 SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
-
-# Seed-debt triage (see tests/test_models.py for the full note): the
-# subprocess imports the mesh helpers which need jax.sharding.AxisType,
-# absent from the container's jax.  Reactivates on a newer jax.
-jax_version_xfail = pytest.mark.xfail(
-    not hasattr(jax.sharding, "AxisType"), strict=False,
-    reason="seed debt: installed jax lacks jax.sharding.AxisType/"
-           "get_abstract_mesh required by the mesh stack")
 
 
 def run_subprocess(code: str) -> dict:
@@ -29,7 +19,6 @@ def run_subprocess(code: str) -> dict:
     return json.loads(out.stdout.strip().splitlines()[-1])
 
 
-@jax_version_xfail
 def test_gpipe_matches_sequential():
     code = textwrap.dedent("""
         import json

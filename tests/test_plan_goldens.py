@@ -198,7 +198,10 @@ def test_snapshot_captures_engines_and_pruning():
     assert "fused_mask" in ops and "scan_star" in ops
     masks = [n for n in snap["nodes"] if n["op"] == "fused_mask"]
     assert all(m["params"].get("engine") == "pallas" for m in masks)
-    assert all(m["params"].get("bitset_block") == 1024 for m in masks)
+    from repro.kernels.predicate import DEFAULT_BLOCK
+
+    assert all(m["params"].get("bitset_block") == DEFAULT_BLOCK
+               for m in masks)
     # bitset-native validity: predicate + compact nodes carry the layout
     # stamp, and the pruned-to-key IR_BEN join is eliminated to a key_count
     layered = [n for n in snap["nodes"] if n["op"] in ("fused_mask", "compact")]

@@ -1,5 +1,6 @@
 """Cross-cutting property tests (system invariants, hypothesis-driven)."""
-from _hyp import given, settings, st
+import hypothesis.strategies as st
+from hypothesis import given, settings
 import jax
 import jax.numpy as jnp
 import numpy as np

@@ -2,7 +2,6 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from repro.models import get_bundle
 from repro.serving.batching import ContinuousBatcher, Request
@@ -10,16 +9,7 @@ from repro.serving.serve_step import greedy_sample, make_serve_step
 
 ARCH = "qwen2-1.5b"
 
-# Seed-debt triage (see tests/test_models.py for the full note): the model
-# stack needs jax.sharding.AxisType/get_abstract_mesh, absent from the
-# container's jax.  Reactivates on a newer jax.
-jax_version_xfail = pytest.mark.xfail(
-    not hasattr(jax.sharding, "AxisType"), strict=False,
-    reason="seed debt: installed jax lacks jax.sharding.AxisType/"
-           "get_abstract_mesh required by the model stack")
 
-
-@jax_version_xfail
 def test_greedy_decode_matches_prefill_argmax():
     b = get_bundle(ARCH, reduced=True)
     params = b.init(jax.random.key(0))
@@ -37,7 +27,6 @@ def test_greedy_decode_matches_prefill_argmax():
     np.testing.assert_array_equal(got, want)
 
 
-@jax_version_xfail
 def test_continuous_batcher_completes_requests():
     b = get_bundle(ARCH, reduced=True)
     params = b.init(jax.random.key(0))
@@ -52,7 +41,6 @@ def test_continuous_batcher_completes_requests():
     assert all(1 <= len(r.out) <= 4 for r in reqs)
 
 
-@jax_version_xfail
 def test_cache_donation_shape_stability():
     """Repeated decode steps keep one cache allocation (donated buffers)."""
     b = get_bundle(ARCH, reduced=True)

@@ -5,7 +5,6 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from repro.models import get_bundle
 from repro.train.optimizer import AdamWConfig, adamw_init, adamw_update, cosine_lr
@@ -19,20 +18,11 @@ from repro.train.grad_compression import (
 
 ARCH = "xlstm-125m"  # smallest reduced config
 
-# Seed-debt triage (see tests/test_models.py for the full note): the model
-# stack needs jax.sharding.AxisType/get_abstract_mesh, absent from the
-# container's jax.  Reactivates on a newer jax.
-jax_version_xfail = pytest.mark.xfail(
-    not hasattr(jax.sharding, "AxisType"), strict=False,
-    reason="seed debt: installed jax lacks jax.sharding.AxisType/"
-           "get_abstract_mesh required by the model stack")
-
 
 def small_batch(cfg, key, B=4, S=32):
     return {"tokens": jax.random.randint(key, (B, S), 3, cfg.vocab_size)}
 
 
-@jax_version_xfail
 def test_loss_decreases():
     b = get_bundle(ARCH, reduced=True)
     step = jax.jit(make_train_step(
@@ -55,7 +45,6 @@ def test_grad_clip_and_lr_schedule():
     assert float(cosine_lr(cfg, jnp.int32(100))) < 1e-4
 
 
-@jax_version_xfail
 def test_checkpoint_restart_is_bit_deterministic(tmp_path):
     """Train 6 steps; vs train 3, checkpoint, restore, train 3 — identical."""
     b = get_bundle(ARCH, reduced=True)
@@ -94,7 +83,6 @@ def test_async_checkpointer(tmp_path):
     assert steps == [20, 30]  # keep=2 GC'd step 10
 
 
-@jax_version_xfail
 def test_microbatch_accumulation_matches_full_batch():
     b = get_bundle(ARCH, reduced=True)
     opt = AdamWConfig(lr_peak=1e-3, warmup_steps=1, total_steps=10)

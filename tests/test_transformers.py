@@ -1,6 +1,7 @@
 """Transformer tests: exposures / follow-up / fractures / trackloss against
 sequential python oracles (including a hypothesis sweep for exposures)."""
-from _hyp import given, settings, st
+import hypothesis.strategies as st
+from hypothesis import given, settings
 import jax.numpy as jnp
 import numpy as np
 import pytest
