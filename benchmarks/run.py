@@ -5,8 +5,6 @@ Prints ``name,us_per_call,derived`` CSV rows:
   * fig3.*        — extraction tasks vs the normalized-join baseline +
                     horizontal-scaling evidence (paper Figure 3)
   * flatten.*     — SCALPEL-Flattening throughput (paper §4)
-  * roofline.*    — per-cell dry-run roofline summary (§Roofline), if the
-                    dry-run matrix artifacts exist
 """
 from __future__ import annotations
 
@@ -390,26 +388,6 @@ def bench_study(n_patients: int = 2_000, repeats: int = 8) -> None:
         _emit(f"study_plan.{r['name']}", r["seconds"] * 1e6, r["derived"])
 
 
-def bench_roofline() -> None:
-    from benchmarks import roofline
-
-    rows = roofline.run()
-    if not rows:
-        _emit("roofline", 0.0, "dry-run artifacts missing (run launch.dryrun)")
-        return
-    for r in rows:
-        if r.get("skipped"):
-            _emit(f"roofline.{r['arch']}.{r['shape']}", 0.0, "skipped")
-            continue
-        dom_t = max(r["t_compute_s"], r["t_memory_s"], r["t_collective_s"])
-        _emit(
-            f"roofline.{r['arch']}.{r['shape']}",
-            dom_t * 1e6,
-            f"dominant={r['dominant']} ratio={r['useful_ratio']:.2f} "
-            f"hbm={r['hbm_gib']:.1f}GiB",
-        )
-
-
 def main() -> None:
     import argparse
 
@@ -446,7 +424,6 @@ def main() -> None:
     bench_chunked()
     bench_analyze()
     bench_spec()
-    bench_roofline()
 
 
 if __name__ == "__main__":

@@ -30,6 +30,7 @@ import numpy as np
 from repro.core.cohort import Cohort, CohortCollection, CohortFlow
 from repro.core.columnar import ColumnarTable
 from repro.core.metadata import OperationLog
+from repro import tracing
 from repro.study import executor as _executor
 from repro.study import optimizer as _optimizer
 from repro.study.expr import CohortRef, parse_cohort_expr
@@ -410,21 +411,23 @@ class Study:
         capacities come out unchanged.  Plans with nothing to capacity-plan
         (no capacity-less expand_join/slice_time node) are content-independent
         and keep the cached path."""
-        raw = self.plan()
-        needs_stats = any(n.op in ("expand_join", "slice_time")
-                          and n.get("capacity") is None for n in raw.nodes)
-        if tables and needs_stats:
-            return _optimizer.optimize(raw, tables=tables, n_shards=n_shards,
-                                       predicate_engine=predicate_engine,
-                                       engine=engine)
-        key = (raw.key(), n_shards, predicate_engine, engine)
-        if self._opt_cache is not None and self._opt_cache[0] == key:
-            return self._opt_cache[1]
-        opt = _optimizer.optimize(raw, n_shards=n_shards,
-                                  predicate_engine=predicate_engine,
-                                  engine=engine)
-        self._opt_cache = (key, opt)
-        return opt
+        with tracing.span("study.optimize"):
+            raw = self.plan()
+            needs_stats = any(n.op in ("expand_join", "slice_time")
+                              and n.get("capacity") is None
+                              for n in raw.nodes)
+            if tables and needs_stats:
+                return _optimizer.optimize(
+                    raw, tables=tables, n_shards=n_shards,
+                    predicate_engine=predicate_engine, engine=engine)
+            key = (raw.key(), n_shards, predicate_engine, engine)
+            if self._opt_cache is not None and self._opt_cache[0] == key:
+                return self._opt_cache[1]
+            opt = _optimizer.optimize(raw, n_shards=n_shards,
+                                      predicate_engine=predicate_engine,
+                                      engine=engine)
+            self._opt_cache = (key, opt)
+            return opt
 
     def check(self, tables: Optional[Dict[str, ColumnarTable]] = None,
               n_shards: int = 1, predicate_engine: str = "auto",
@@ -473,33 +476,42 @@ class Study:
         stamps the resolved choice — and the ``bitset_u32`` validity layout
         — on each node so the OperationLog records it.
         """
-        env = dict(self._sources)
-        env.update(tables or {})
-        n_shards = mesh.shape[axis_name] if mesh is not None else 1
-        plan = (self.optimized_plan(tables=env, n_shards=n_shards,
-                                    predicate_engine=predicate_engine or "auto",
-                                    engine=engine)
-                if optimize else self.plan())
-        log = log if log is not None else OperationLog()
+        with tracing.span("study.run") as s:
+            s.count("n_patients", self.n_patients)
+            env = dict(self._sources)
+            env.update(tables or {})
+            n_shards = mesh.shape[axis_name] if mesh is not None else 1
+            if optimize:
+                plan = self.optimized_plan(
+                    tables=env, n_shards=n_shards,
+                    predicate_engine=predicate_engine or "auto",
+                    engine=engine)
+            else:
+                plan = self.plan()
+            log = log if log is not None else OperationLog()
 
-        join_stats: Dict[int, Dict[str, int]] = {}
-        if mesh is not None:
-            from repro.distributed.pipeline import execute_plan_sharded
+            join_stats: Dict[int, Dict[str, int]] = {}
+            if mesh is not None:
+                from repro.distributed.pipeline import execute_plan_sharded
 
-            vals, counts, join_stats = execute_plan_sharded(
-                plan, env, self.n_patients, mesh, axis_name=axis_name,
-                engine=engine, predicate_engine=predicate_engine)
-            _executor.record_plan(plan, counts, log, engine,
-                                  stats=join_stats,
-                                  predicate_engine=predicate_engine)
-        else:
-            vals = _executor.execute(plan, env, n_patients=self.n_patients,
-                                     engine=engine, log=log, jit=jit,
-                                     stats_sink=join_stats,
-                                     predicate_engine=predicate_engine)
-        for i, d in join_stats.items():
-            d.setdefault("stage", plan.nodes[i].label())
-        return self._finish_result(plan, vals, join_stats, log)
+                with tracing.span("study.execute"):
+                    vals, counts, join_stats = execute_plan_sharded(
+                        plan, env, self.n_patients, mesh,
+                        axis_name=axis_name, engine=engine,
+                        predicate_engine=predicate_engine)
+                    with tracing.span("execute.record"):
+                        _executor.record_plan(
+                            plan, counts, log, engine, stats=join_stats,
+                            predicate_engine=predicate_engine)
+            else:
+                vals = _executor.execute(plan, env,
+                                         n_patients=self.n_patients,
+                                         engine=engine, log=log, jit=jit,
+                                         stats_sink=join_stats,
+                                         predicate_engine=predicate_engine)
+            for i, d in join_stats.items():
+                d.setdefault("stage", plan.nodes[i].label())
+            return self._finish_result(plan, vals, join_stats, log)
 
     def run_chunked(self, store, tables: Optional[Dict[str, ColumnarTable]] = None,
                     engine: str = "xla", predicate_engine: Optional[str] = None,
@@ -541,88 +553,103 @@ class Study:
         service's cached runner, after mapping canonical ids back) returns.
         Factored out of ``run`` so ``study.service`` produces bit-identical
         results through the same realization code."""
-        nodes = plan.nodes
-        out_ids = plan.output_ids
-        events = {name: vals[i] for name, i in out_ids.items()
-                  if nodes[i].op in TABLE_OPS and i in vals}
+        with tracing.span("study.realize"):
+            nodes = plan.nodes
+            out_ids = plan.output_ids
+            events = {name: vals[i] for name, i in out_ids.items()
+                      if nodes[i].op in TABLE_OPS and i in vals}
 
-        # realize cohorts by replaying the algebra on wrapped operands — the
-        # thin eager layer keeps description/window/event semantics identical
-        # to the interactive Cohort API.  A node can carry several names when
-        # two cohort expressions hash-cons to the same sub-plan (aliases), so
-        # names are grouped, never inverted into an id-keyed dict.
-        names_by_id: Dict[int, List[str]] = {}
-        for name, i in out_ids.items():
-            if nodes[i].op in COHORT_OPS:
-                names_by_id.setdefault(i, []).append(name)
-        cohort_names = {i: ns[0] for i, ns in names_by_id.items()}
-        realized: Dict[int, Cohort] = {}
+            # realize cohorts by replaying the algebra on wrapped operands —
+            # the thin eager layer keeps description/window/event semantics
+            # identical to the interactive Cohort API.  A node can carry
+            # several names when two cohort expressions hash-cons to the same
+            # sub-plan (aliases), so names are grouped, never inverted into
+            # an id-keyed dict.
+            names_by_id: Dict[int, List[str]] = {}
+            for name, i in out_ids.items():
+                if nodes[i].op in COHORT_OPS:
+                    names_by_id.setdefault(i, []).append(name)
+            cohort_names = {i: ns[0] for i, ns in names_by_id.items()}
+            realized: Dict[int, Cohort] = {}
 
-        def _realize(i: int) -> Cohort:
-            if i in realized:
-                return realized[i]
-            node = nodes[i]
-            if node.op == "cohort_from_events":
-                nm = node.get("name")
-                ev = vals.get(node.inputs[0])
-                c = Cohort(name=nm, description=f"subjects with event {nm}",
-                           subjects=vals[i], n_patients=self.n_patients,
-                           events=ev, window=self._window)
-            else:
-                left = _realize(node.inputs[0])
-                right = _realize(node.inputs[1])
-                kind = node.get("kind")
-                c = (left.intersection(right) if kind == "&"
-                     else left.union(right) if kind == "|"
-                     else left.difference(right))
-            if i in cohort_names:
-                c.name = cohort_names[i]
-            realized[i] = c
-            return c
+            def _realize(i: int) -> Cohort:
+                if i in realized:
+                    return realized[i]
+                node = nodes[i]
+                if node.op == "cohort_from_events":
+                    nm = node.get("name")
+                    ev = vals.get(node.inputs[0])
+                    c = Cohort(name=nm,
+                               description=f"subjects with event {nm}",
+                               subjects=vals[i], n_patients=self.n_patients,
+                               events=ev, window=self._window)
+                else:
+                    left = _realize(node.inputs[0])
+                    right = _realize(node.inputs[1])
+                    kind = node.get("kind")
+                    c = (left.intersection(right) if kind == "&"
+                         else left.union(right) if kind == "|"
+                         else left.difference(right))
+                if i in cohort_names:
+                    c.name = cohort_names[i]
+                realized[i] = c
+                return c
 
-        cohorts = {}
-        for i, names in names_by_id.items():
-            c = _realize(i)
-            for name in names:
-                cohorts[name] = (c if c.name == name
-                                 else dataclasses.replace(c, name=name))
+            cohorts = {}
+            with tracing.span("realize.cohorts"):
+                for i, names in names_by_id.items():
+                    c = _realize(i)
+                    for name in names:
+                        cohorts[name] = (
+                            c if c.name == name
+                            else dataclasses.replace(c, name=name))
 
-        flow = None
-        if self._flow_names:
-            fid = out_ids[_FLOW_OUT]
-            flow = CohortFlow([_realize(j) for j in nodes[fid].inputs])
-            prev = None
-            for nm, stage in zip(self._flow_names, flow.steps):
-                n = stage.subject_count()
-                log.record(op=f"flow:{nm}",
-                           inputs={} if prev is None else {"prev": _Count(prev)},
-                           outputs={nm: _Count(n)}, params={})
-                prev = n
+            flow = None
+            if self._flow_names:
+                with tracing.span("realize.flow") as s:
+                    fid = out_ids[_FLOW_OUT]
+                    flow = CohortFlow([_realize(j) for j in nodes[fid].inputs])
+                    prev = None
+                    for nm, stage in zip(self._flow_names, flow.steps):
+                        n = stage.subject_count()
+                        s.count("host_syncs")
+                        log.record(op=f"flow:{nm}",
+                                   inputs={} if prev is None
+                                   else {"prev": _Count(prev)},
+                                   outputs={nm: _Count(n)}, params={})
+                        prev = n
 
-        features: Dict[str, Any] = {}
-        checks: Dict[str, Dict[str, int]] = {}
-        for name in self._feature_names:
-            fnode = nodes[out_ids[name]]
-            cohort = _realize(fnode.inputs[0])
-            pats = vals.get(fnode.inputs[1]) if len(fnode.inputs) > 1 else None
-            from repro.core.feature_driver import FeatureDriver
+            features: Dict[str, Any] = {}
+            checks: Dict[str, Dict[str, int]] = {}
+            for name in self._feature_names:
+                fnode = nodes[out_ids[name]]
+                with tracing.span("realize.featurize", name=name,
+                                  kind=fnode.get("kind")) as s:
+                    cohort = _realize(fnode.inputs[0])
+                    pats = (vals.get(fnode.inputs[1]) if len(fnode.inputs) > 1
+                            else None)
+                    from repro.core.feature_driver import FeatureDriver
 
-            fd = FeatureDriver(cohort, pats)
-            kwargs = {k: v for k, v in (fnode.get("kwargs") or ())}
-            if fnode.get("kind") == "dense":
-                features[name] = fd.dense_features(**kwargs)
-            else:
-                features[name] = fd.token_sequences(**kwargs)
-            checks[name] = dict(fd.checks)
-            log.record(op=f"featurize:{name}",
-                       inputs={cohort.name: _Count(cohort.subject_count())},
-                       outputs={name: _Count(checks[name].get(
-                           "events_total", 0))},
-                       params={"kind": fnode.get("kind")})
+                    fd = FeatureDriver(cohort, pats)
+                    kwargs = {k: v for k, v in (fnode.get("kwargs") or ())}
+                    if fnode.get("kind") == "dense":
+                        features[name] = fd.dense_features(**kwargs)
+                    else:
+                        features[name] = fd.token_sequences(**kwargs)
+                    checks[name] = dict(fd.checks)
+                    n_subjects = cohort.subject_count()
+                    # each check is one read of a device count, and so is the
+                    # cohort's subject count
+                    s.count("host_syncs", len(checks[name]) + 1)
+                    log.record(op=f"featurize:{name}",
+                               inputs={cohort.name: _Count(n_subjects)},
+                               outputs={name: _Count(checks[name].get(
+                                   "events_total", 0))},
+                               params={"kind": fnode.get("kind")})
 
-        return StudyResult(events=events, cohorts=cohorts, flow=flow,
-                           features=features, log=log, plan=plan,
-                           feature_checks=checks, flatten_stats=join_stats)
+            return StudyResult(events=events, cohorts=cohorts, flow=flow,
+                               features=features, log=log, plan=plan,
+                               feature_checks=checks, flatten_stats=join_stats)
 
 
 class _Count:

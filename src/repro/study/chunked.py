@@ -54,7 +54,6 @@ import dataclasses
 import hashlib
 import json
 import os
-import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, List, Optional, Set, Tuple
 
@@ -62,6 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core.columnar import ColumnarTable
 from repro.core.metadata import OperationLog
 from repro.data.chunkstore import ChunkStore
@@ -570,53 +570,56 @@ class ChunkedExecutor:
 
         todo = [ci for ci in range(store.n_chunks) if ci not in done]
 
-        def _load(ci: int) -> Tuple[ColumnarTable, float]:
-            t0 = time.perf_counter()
-            # chunk 0 was already loaded for planning/preflight — reuse it
-            t = chunk0 if ci == 0 else store.chunk_table(ci)
-            jax.block_until_ready(t.valid)   # staging done, not just enqueued
-            return t, time.perf_counter() - t0
+        def _load(ci: int, loop: tracing.Span
+                  ) -> Tuple[ColumnarTable, float]:
+            with tracing.span("chunked.load", parent=loop, chunk=ci) as s:
+                # chunk 0 was already loaded for planning/preflight — reuse it
+                t = chunk0 if ci == 0 else store.chunk_table(ci)
+                jax.block_until_ready(t.valid)  # staging done, not just enqueued
+            return t, s.seconds
 
         pool = ThreadPoolExecutor(max_workers=1) if self.prefetch and todo \
             else None
-        t_loop = time.perf_counter()
-        try:
-            fut = pool.submit(_load, todo[0]) if pool else None
-            for pos, ci in enumerate(todo):
-                if self.crash_after is not None and \
-                        rep.executed >= self.crash_after:
-                    raise _InjectedCrash(
-                        f"injected crash after {rep.executed} chunks")
-                chunk, load_s = fut.result() if fut else _load(ci)
-                rep.load_s += load_s
-                if pool and pos + 1 < len(todo):
-                    fut = pool.submit(_load, todo[pos + 1])
-                t0 = time.perf_counter()
-                stats_sink: Dict[int, Dict[str, int]] = {}
-                vals = _executor.execute(
-                    plan, self._chunk_env(resident, chunk),
-                    n_patients=study.n_patients, engine=self.engine,
-                    log=None, jit=True, stats_sink=stats_sink,
-                    predicate_engine=self.predicate_engine)
-                jax.block_until_ready(vals)
-                exec_s = time.perf_counter() - t0
-                rep.exec_s += exec_s
-                counts = {i: int(np.asarray(vals[i].count))
-                          if isinstance(vals[i], ColumnarTable)
-                          else int(np.bitwise_count(np.asarray(vals[i]))
-                                   .sum())
-                          for i in vals}
-                if self.checkpoint_dir is not None:
-                    self._commit_chunk(ci, vals, counts, stats_sink, plan)
-                merge(ci, vals, counts, stats_sink)
-                rep.executed += 1
-                log.record(op=f"chunked:chunk:{ci}", inputs={}, outputs={},
-                           params={"chunk": ci, "load_s": round(load_s, 6),
-                                   "exec_s": round(exec_s, 6)})
-        finally:
-            if pool:
-                pool.shutdown(wait=False, cancel_futures=True)
-        rep.wall_s = time.perf_counter() - t_loop
+        with tracing.span("chunked.loop") as loop:
+            try:
+                fut = pool.submit(_load, todo[0], loop) if pool else None
+                for pos, ci in enumerate(todo):
+                    if self.crash_after is not None and \
+                            rep.executed >= self.crash_after:
+                        raise _InjectedCrash(
+                            f"injected crash after {rep.executed} chunks")
+                    chunk, load_s = fut.result() if fut else _load(ci, loop)
+                    rep.load_s += load_s
+                    if pool and pos + 1 < len(todo):
+                        fut = pool.submit(_load, todo[pos + 1], loop)
+                    stats_sink: Dict[int, Dict[str, int]] = {}
+                    with tracing.span("chunked.exec", chunk=ci) as ex:
+                        vals = _executor.execute(
+                            plan, self._chunk_env(resident, chunk),
+                            n_patients=study.n_patients, engine=self.engine,
+                            log=None, jit=True, stats_sink=stats_sink,
+                            predicate_engine=self.predicate_engine)
+                        jax.block_until_ready(vals)
+                    exec_s = ex.seconds
+                    rep.exec_s += exec_s
+                    counts = {i: int(np.asarray(vals[i].count))
+                              if isinstance(vals[i], ColumnarTable)
+                              else int(np.bitwise_count(np.asarray(vals[i]))
+                                       .sum())
+                              for i in vals}
+                    if self.checkpoint_dir is not None:
+                        self._commit_chunk(ci, vals, counts, stats_sink, plan)
+                    merge(ci, vals, counts, stats_sink)
+                    rep.executed += 1
+                    log.record(op=f"chunked:chunk:{ci}", inputs={},
+                               outputs={},
+                               params={"chunk": ci,
+                                       "load_s": round(load_s, 6),
+                                       "exec_s": round(exec_s, 6)})
+            finally:
+                if pool:
+                    pool.shutdown(wait=False, cancel_futures=True)
+        rep.wall_s = loop.seconds
         rep.compiles = _executor.jit_cache_info()["compiles"] - compiles0
 
         # -- merge into one StudyResult -------------------------------------

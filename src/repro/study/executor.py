@@ -32,6 +32,7 @@ from repro.core.cohort import Bitset
 from repro.core.columnar import ColumnarTable, is_null
 from repro.core.events import make_events
 from repro.core.metadata import OperationLog
+from repro import tracing
 from repro.kernels import predicate as _pk
 from repro.study import expr as _expr
 from repro.study.plan import (COHORT_OPS, PREDICATE_OPS, Plan, STATS_OPS,
@@ -417,40 +418,60 @@ def execute(plan: Plan, tables: Dict[str, ColumnarTable], n_patients: int = 0,
     their shape/dtype signature — same structure + different literals reuses
     one executable.
     """
+    with tracing.span("study.execute"):
+        return _execute(plan, tables, n_patients, engine, log, jit,
+                        stats_sink, predicate_engine, expr_params)
+
+
+def _execute(plan, tables, n_patients, engine, log, jit, stats_sink,
+             predicate_engine, expr_params) -> Dict[int, Any]:
     missing = [s for s in plan.sources() if s not in tables]
     if missing:
         raise KeyError(f"plan scans source(s) {missing} but run() only got "
                        f"{sorted(tables)}")
     env = {src: tables[src] for src in plan.sources()}
     if jit:
-        if expr_params is None:
-            fn, args = _jitted_runner(
-                plan, n_patients, engine, predicate_engine), (env,)
-        else:
-            from repro.study.normalize import params_signature
+        with tracing.span("execute.dispatch") as s:
+            compiles = _JIT_STATS["compiles"]
+            if expr_params is None:
+                fn, args = _jitted_runner(
+                    plan, n_patients, engine, predicate_engine), (env,)
+            else:
+                from repro.study.normalize import params_signature
 
-            lits, vecs = expr_params
-            fn = _jitted_runner(plan, n_patients, engine, predicate_engine,
-                                params_sig=params_signature(lits, vecs))
-            args = (env, tuple(lits), tuple(vecs))
-        vals, counts_vec, stats = fn(*args)
-        counts = dict(zip(traced_ids(plan),
-                          (int(c) for c in np.asarray(counts_vec))))
+                lits, vecs = expr_params
+                fn = _jitted_runner(plan, n_patients, engine,
+                                    predicate_engine,
+                                    params_sig=params_signature(lits, vecs))
+                args = (env, tuple(lits), tuple(vecs))
+            s.count("compiled", _JIT_STATS["compiles"] > compiles)
+            vals, counts_vec, stats = fn(*args)
+        with tracing.span("execute.wait") as s:
+            # the first read of the program's outputs waits for it to end
+            counts_host = np.asarray(counts_vec)
+            s.count("host_syncs")
+        counts = dict(zip(traced_ids(plan), (int(c) for c in counts_host)))
     else:
-        lits, vecs = expr_params or ((), ())
-        with _expr.bound_params(lits, vecs):
-            vals, counts_dev, stats = run_plan_body(
-                plan, env, n_patients, engine,
-                predicate_engine=predicate_engine)
-        vals = {i: vals[i] for i in keep_ids(plan)}
-        counts = {i: int(c) for i, c in counts_dev.items()}
+        with tracing.span("execute.dispatch"):
+            lits, vecs = expr_params or ((), ())
+            with _expr.bound_params(lits, vecs):
+                vals, counts_dev, stats = run_plan_body(
+                    plan, env, n_patients, engine,
+                    predicate_engine=predicate_engine)
+            vals = {i: vals[i] for i in keep_ids(plan)}
+        with tracing.span("execute.wait") as s:
+            counts = {i: int(c) for i, c in counts_dev.items()}
+            s.count("host_syncs", len(counts))
     if log is not None or stats_sink is not None:
         # host conversion is one blocking transfer per stat scalar — only
         # pay it when someone consumes the stats
-        host_stats = _host_stats(stats)
+        with tracing.span("execute.stats") as s:
+            host_stats = _host_stats(stats)
+            s.count("host_syncs", sum(len(d) for d in host_stats.values()))
         if log is not None:
-            record_plan(plan, counts, log, engine, stats=host_stats,
-                        predicate_engine=predicate_engine)
+            with tracing.span("execute.record"):
+                record_plan(plan, counts, log, engine, stats=host_stats,
+                            predicate_engine=predicate_engine)
         if stats_sink is not None:
             stats_sink.update(host_stats)
     return vals

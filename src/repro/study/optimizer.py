@@ -41,8 +41,10 @@ from __future__ import annotations
 
 from typing import Dict, FrozenSet, List, Mapping, Optional, Set, Tuple
 
+import jax
 import numpy as np
 
+from repro import tracing
 from repro.core.columnar import NULL_INT
 from repro.kernels import predicate as _pk
 from repro.study import expr as _expr
@@ -525,6 +527,12 @@ def plan_capacities(plan: Plan, tables: Mapping, round_to: int = 64,
     """
     if not any(n.op in ops and n.get("capacity") is None for n in plan.nodes):
         return plan  # nothing consumes table statistics — skip the sim
+    with tracing.span("optimize.plan_capacities") as s:
+        return _plan_capacities(plan, tables, round_to, ops, s)
+
+
+def _plan_capacities(plan: Plan, tables: Mapping, round_to: int,
+                     ops: Tuple[str, ...], s: tracing.Span) -> Plan:
     needed = set()
     for n in plan.nodes:
         if n.op in JOIN_OPS:
@@ -547,9 +555,13 @@ def plan_capacities(plan: Plan, tables: Mapping, round_to: int = 64,
             if t is None:
                 sim[i] = None
                 continue
+            cols = [c for c in needed if c in t.columns]
             valid = t.valid_numpy()
-            sim[i] = {c: np.asarray(t.columns[c])[valid]
-                      for c in needed if c in t.columns}
+            sim[i] = {c: np.asarray(t.columns[c])[valid] for c in cols}
+            reads = [a for a in [t.valid] + [t.columns[c] for c in cols]
+                     if isinstance(a, jax.Array)]
+            s.count("host_syncs", len(reads))
+            s.count("bytes_to_host", sum(a.nbytes for a in reads))
         elif n.op == "select":
             up = sim.get(n.inputs[0])
             sim[i] = (None if up is None else
@@ -577,6 +589,7 @@ def plan_capacities(plan: Plan, tables: Mapping, round_to: int = 64,
                     or rk_name not in right:
                 sim[i] = None
                 continue
+            s.count("joins")
             lk = left[lk_name]
             rk = right[rk_name]
             rs = np.sort(rk[~_np_null_mask(rk)])

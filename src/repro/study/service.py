@@ -64,7 +64,6 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-import time
 from collections import OrderedDict, deque
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -73,6 +72,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core.columnar import ColumnarTable
 from repro.core.metadata import OperationLog
 from repro.kernels import predicate as _pk
@@ -197,9 +197,13 @@ class QueryTicket:
     cache_hits: int = 0
     cache_misses: int = 0
     compiled: bool = False            # this query built a new executable
-    latency_s: float = 0.0
+    latency_s: float = 0.0            # device-submit start to realized
     submit_s: float = 0.0             # device-submit stage time
     realize_s: float = 0.0            # host-realize stage time
+    # the ``service.queued`` span, from submit() to admission: the root of
+    # the query's spans
+    _queued: Optional[tracing.Span] = dataclasses.field(
+        default=None, repr=False, compare=False)
     # in-flight cut registration (see _cut_lookup / _release_cuts)
     _cut_evt: Optional[threading.Event] = dataclasses.field(
         default=None, repr=False, compare=False)
@@ -384,10 +388,14 @@ class CohortQueryService:
         reject (``status == "rejected"``)."""
         t = QueryTicket(tenant=tenant, study=study, priority=int(priority),
                         seq=self._seq, wire=wire)
+        t._queued = tracing.begin("service.queued", ticket=t.seq,
+                                  tenant=tenant)
         self._seq += 1
         with self._lock:
             self.stats.tenant(tenant).submitted += 1
         if not self._sched.submit(t, key=tenant, priority=priority):
+            t._queued.attrs["rejected"] = True
+            t._queued.end()
             t.status = "rejected"
             with self._lock:
                 self.stats.tenant(tenant).rejected += 1
@@ -448,6 +456,7 @@ class CohortQueryService:
         self._reap(block=False)
         admitted = self._sched.admit()
         for ticket, tenant in admitted:
+            ticket._queued.end()
             with self._lock:
                 self.stats.tenant(tenant).admitted += 1
             try:
@@ -471,17 +480,17 @@ class CohortQueryService:
         resolved.  The elapsed wall accrues into ``stats.wall_s`` — the
         baseline the pipeline's ``overlap_s`` accounting is measured
         against."""
-        t0 = time.perf_counter()
-        while True:
-            if self.step():
-                continue
-            if self._pending:
-                # nothing admittable: a finishing realization frees slots
-                self._reap(block=True)
-                continue
-            break
+        with tracing.span("service.drain") as d:
+            while True:
+                if self.step():
+                    continue
+                if self._pending:
+                    # nothing admittable: a finishing realization frees slots
+                    self._reap(block=True)
+                    continue
+                break
         with self._lock:
-            self.stats.wall_s += time.perf_counter() - t0
+            self.stats.wall_s += d.seconds
 
     def query(self, study: Study, tenant: str = "default",
               priority: int = 0) -> StudyResult:
@@ -591,44 +600,43 @@ class CohortQueryService:
         """Device-submit stage: optimize, admission analysis, normalize,
         program + cache lookup, dispatch.  Returns the host-realize closure
         (run by ``_realize_ticket``, possibly on the worker)."""
-        t0 = time.perf_counter()
         study = ticket.study
-        peng_arg = self.config.predicate_engine
-        # a mesh plan keeps its exchanges and per-shard capacities: optimized
-        # for one shard, joins would only match rows that happen to share a
-        # device
-        n_shards = (self.mesh.shape[self.axis_name]
-                    if self.mesh is not None else 1)
-        plan = study.optimized_plan(tables=self._env, n_shards=n_shards,
-                                    predicate_engine=peng_arg or "auto",
-                                    engine=self.config.engine)
-        # admission-time static analysis: error-level plans (unknown
-        # sources, dropped-column reads, provably-empty masks, kind
-        # mismatches) are rejected BEFORE they reach normalization or the
-        # compile cache — a broken tenant plan must not cost a compile slot
-        # or poison shared executables
-        diags = _analyze_plan(plan, tables=self._env, n_shards=n_shards,
-                              n_patients=study.n_patients)
-        if any(d.severity == "error" for d in diags):
-            raise PlanValidationError(diags)
-        if self.mesh is not None:
-            realize_vals = self._run_sharded(ticket, study, plan)
-        else:
-            realize_vals = self._run_local(ticket, study, plan)
-        ticket.submit_s = time.perf_counter() - t0
+        with tracing.span("service.submit", parent=ticket._queued) as sub:
+            peng_arg = self.config.predicate_engine
+            # a mesh plan keeps its exchanges and per-shard capacities:
+            # optimized for one shard, joins would only match rows that
+            # happen to share a device
+            n_shards = (self.mesh.shape[self.axis_name]
+                        if self.mesh is not None else 1)
+            plan = study.optimized_plan(tables=self._env, n_shards=n_shards,
+                                        predicate_engine=peng_arg or "auto",
+                                        engine=self.config.engine)
+            # admission-time static analysis: error-level plans (unknown
+            # sources, dropped-column reads, provably-empty masks, kind
+            # mismatches) are rejected BEFORE they reach normalization or
+            # the compile cache — a broken tenant plan must not cost a
+            # compile slot or poison shared executables
+            diags = _analyze_plan(plan, tables=self._env, n_shards=n_shards,
+                                  n_patients=study.n_patients)
+            if any(d.severity == "error" for d in diags):
+                raise PlanValidationError(diags)
+            if self.mesh is not None:
+                realize_vals = self._run_sharded(ticket, study, plan)
+            else:
+                realize_vals = self._run_local(ticket, study, plan)
+        ticket.submit_s = sub.seconds
         with self._lock:
             self.stats.submit_s += ticket.submit_s
 
         def realize() -> None:
-            t1 = time.perf_counter()
-            vals, stats_orig, req_log = realize_vals()
-            for i, d in stats_orig.items():
-                d.setdefault("stage", plan.nodes[i].label())
-            ticket.result = study._finish_result(plan, vals, stats_orig,
-                                                 req_log)
-            now = time.perf_counter()
-            ticket.realize_s = now - t1
-            ticket.latency_s = now - t0
+            with tracing.span("service.realize", parent=sub) as rs:
+                vals, stats_orig, req_log = realize_vals()
+                for i, d in stats_orig.items():
+                    d.setdefault("stage", plan.nodes[i].label())
+                ticket.result = study._finish_result(plan, vals, stats_orig,
+                                                     req_log)
+            ticket.realize_s = rs.seconds
+            ticket.latency_s = (rs.end_ns - sub.start_ns) * 1e-9
             with self._lock:
                 self.stats.realize_s += ticket.realize_s
                 self.stats.queries += 1
