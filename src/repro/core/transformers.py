@@ -7,7 +7,9 @@ events (drug exposures, outcomes, follow-up...).  With events kept sorted by
 of a patient-partitioned table (DESIGN.md §2).
 
 Implemented (paper Table 4): observation period, follow-up, trackloss,
-exposures (limited/unlimited), fractures-per-body-site outcome.
+exposures (limited/unlimited), fractures-per-body-site outcome.  The
+fractures washout chain is the one sequential fold: a loop over the sorted
+candidates only, never over the table's capacity.
 """
 from __future__ import annotations
 
@@ -227,8 +229,11 @@ def fractures(
     washout window.
 
     The greedy per-(patient, site) washout chain is order-dependent, so it is
-    a genuine ``lax.scan`` (the only sequential transformer); everything
-    before it is columnar.
+    a genuine sequential loop (the only sequential transformer); everything
+    before it is columnar.  ``sort_by`` sinks invalid rows, so the candidates
+    are the sorted table's first ``count`` rows: the loop walks only those,
+    its trip count the traced ``count`` (one program for every count), and
+    every row past them stays dropped.
     """
     a_codes = jnp.asarray(np.asarray(fracture_act_codes, np.int32))
     d_codes = jnp.asarray(np.asarray(fracture_diag_codes, np.int32))
@@ -246,19 +251,17 @@ def fractures(
     sit = cand.columns["site"]
     dat = cand.columns["start"]
 
-    def body(carry, x):
-        prev_p, prev_s, prev_d = carry
-        p, s, t, v = x
+    def body(i, carry):
+        prev_p, prev_s, prev_d, keep = carry
+        p, s, t = pid[i], sit[i], dat[i]
         fresh = (p != prev_p) | (s != prev_s) | (t - prev_d >= washout_days)
-        keep = v & fresh
-        return (
-            jnp.where(keep, p, prev_p),
-            jnp.where(keep, s, prev_s),
-            jnp.where(keep, t, prev_d),
-        ), keep
+        return (jnp.where(fresh, p, prev_p), jnp.where(fresh, s, prev_s),
+                jnp.where(fresh, t, prev_d), keep.at[i].set(fresh))
 
-    init = (jnp.int32(-1), jnp.int32(-1), jnp.int32(-2_000_000_000))
-    _, keep = jax.lax.scan(body, init, (pid, sit, dat, cand.valid_bool()))
+    keep = jnp.zeros((cand.capacity,), bool)
+    if cand.capacity:
+        init = (jnp.int32(-1), jnp.int32(-1), jnp.int32(-2_000_000_000), keep)
+        *_, keep = jax.lax.fori_loop(0, cand.count, body, init)
 
     kept = cand.filter(keep)
     return make_events(
