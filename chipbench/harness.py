@@ -1,13 +1,25 @@
 """The data-driven harness: cell lookup, compile cache, set-up and window,
 the trace, the per-layer readers and the result line.
 
-Everything that belongs to one configuration, traffic mix, per-layer metric
-or kernel lives in a file of its own that this module finds by name:
+Everything that belongs to one configuration, traffic mix, loop, study
+design, per-layer metric or kernel lives in a file of its own that this
+module, or the loop, finds by name:
 
 - ``chipbench/configs/<config>.json``
-- ``chipbench/traffic/<traffic>.json`` (its ``kind`` picks the loop)
+- ``chipbench/traffic/<traffic>.json``, whose ``kind`` names the loop
+- ``chipbench/loops/<kind>.py``: ``Cell(cfg, mix, seed, devices)`` with
+  ``setup``, ``window``, ``end_to_end``, ``counts``, ``take_outputs``,
+  ``reference`` and ``check``; optionally ``TRACE_SECONDS``, the part of
+  the window a ``--trace 1`` run traces (the whole window without it)
+- ``chipbench/designs/<design>.py``: a study, its reference and their
+  comparison, named by the configuration and found by the loop that runs it
 - ``chipbench/metrics/<metric>.py`` (``read(run) -> float | None``)
-- ``chipbench/kernels/<kernel>.py`` (``TRACE_NAMES`` and ``cost(event)``)
+- ``chipbench/kernels/<kernel>.py`` (``matches``, ``cost`` and optionally
+  ``fits``)
+
+So a new loop kind, design, configuration, mix, cell or per-layer metric is
+new files and ``BENCHMARK.json`` entries; a new end-to-end metric, or any
+edit of a file that is already here, takes a ``benchmark`` change.
 """
 from __future__ import annotations
 
@@ -24,10 +36,6 @@ from typing import Dict, Optional
 
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-#: a ``--trace 1`` run traces this much of its window at most, so a
-#: closed-loop window traces one study: a study's sequential scans put
-#: millions of op events in a trace, and the profiler keeps 6 Mi
-TRACE_SECONDS = 0.0
 
 
 def load_benchmark(root: str) -> Dict:
@@ -50,7 +58,7 @@ def load_config(name: str) -> Dict:
 
 def enable_compile_cache(root: str) -> str:
     """JAX's persistent compilation cache at a fixed path in the checkout,
-    keeping every program (the study's eager host ops too), so that only a
+    keeping every program (eager host ops too), so that only a
     cell's first run in a checkout compiles."""
     import jax
 
@@ -63,7 +71,14 @@ def enable_compile_cache(root: str) -> str:
 
 
 def _load_path(kind: str, name: str):
+    """The module ``chipbench/<kind>/<name>.py``; an unknown name is refused
+    with the names found there."""
     path = os.path.join(HERE, kind, f"{name}.py")
+    if not os.path.isfile(path):
+        found = sorted(os.path.basename(p)[:-3] for p in
+                       glob.glob(os.path.join(HERE, kind, "*.py")))
+        raise ValueError(f"no {kind}/{name}.py in {HERE}; {kind}/ has "
+                         f"{found}")
     spec = importlib.util.spec_from_file_location(
         f"chipbench_{kind}_{name.replace('.', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
@@ -103,7 +118,7 @@ class CompileCount:
 
 
 class Run:
-    """What the per-layer readers read: the cell's driver (its spans and
+    """What the per-layer readers read: the cell's loop (its spans and
     counts), the reduced trace and the kernel models."""
 
     def __init__(self, cell: Dict, driver, trace, kernels: Dict, peaks: Dict):
@@ -112,13 +127,6 @@ class Run:
         self.trace = trace
         self.kernels = kernels
         self.peaks = peaks
-
-
-def _driver(cfg: Dict, mix: Dict, seed: int):
-    if mix["kind"] == "closed_study":
-        from chipbench.study_cell import StudyCell
-        return StudyCell(cfg, mix, seed)
-    raise ValueError(f"unknown traffic kind {mix['kind']!r}")
 
 
 def _say(msg: str) -> None:
@@ -138,7 +146,8 @@ def run_cell(bench: Dict, cell: Dict, seed: int, seconds: float, trace: bool,
     mix = mix if mix is not None else traffic.load(cell["traffic"])
     compiles = CompileCount()
     t0 = time.perf_counter()
-    driver = _driver(cfg, mix, seed)
+    loop = _load_path("loops", mix["kind"])
+    driver = loop.Cell(cfg, mix, seed, devices)
     driver.setup(seconds)
     setup_s = time.perf_counter() - t0
     _say(f"set-up {setup_s:.3f} s, {compiles.n} compiles "
@@ -149,7 +158,8 @@ def run_cell(bench: Dict, cell: Dict, seed: int, seconds: float, trace: bool,
     try:
         if trace:
             with trace_mod.Tracer(tdir) as tracer:
-                driver.window(min(seconds, TRACE_SECONDS))
+                driver.window(min(seconds, getattr(loop, "TRACE_SECONDS",
+                                                   seconds)))
         else:
             driver.window(seconds)
         in_window = compiles.n - n_before
@@ -164,13 +174,13 @@ def run_cell(bench: Dict, cell: Dict, seed: int, seconds: float, trace: bool,
                  f"events read in {time.perf_counter() - t_read:.3f} s")
             if reduced.full:
                 _say("trace: the profiler's buffer filled and dropped ops; "
-                     "the per-study metrics are left out")
+                     "the readers of whole spans leave their metrics out")
     finally:
         if tdir:
             shutil.rmtree(tdir, ignore_errors=True)
     _say(f"compiles inside the window: {in_window}")
     attempted, failed = driver.counts()
-    _say(f"studies completed in the window: {attempted}")
+    _say(f"completed in the window: {attempted}")
 
     d0 = devices[0]
     if trace:

@@ -1,9 +1,10 @@
 """Device time of loops per study: the union of the device's ``while``
 instructions inside the traced studies, over their number, averaged over
-the chips.  The loops are the transforms' sequential ``lax.scan``s (the
-fractures transform steps once per row of its acts) and
-``columnar.cumsum``'s segment scans; the ops of a loop's body run inside
-its ``while`` and are counted with it."""
+the chips.  The loops are ``jnp.searchsorted``'s level loops in
+``ColumnarTable.compact`` and the lookup joins, the fractures transform's
+washout loop (one step per sorted valid candidate) and ``columnar.cumsum``'s
+segment scans; the ops of a loop's body run inside its ``while`` and are
+counted with it."""
 import numpy as np
 
 

@@ -5,14 +5,16 @@
 
 The cell (configuration, traffic mix, chips, metrics) is read from
 ``BENCHMARK.json`` at the root of the checkout; the configuration file, the
-traffic mix, the per-layer metric readers and the kernel models are found by
-name under ``chipbench/``.  The run refuses a platform other than TPU and
-fewer chips than the cell asks for, and then prints no result.
+traffic mix, the loop its ``kind`` names, the study design, the per-layer
+metric readers and the kernel models are found by name under
+``chipbench/``.  The run refuses a platform other than TPU and fewer chips
+than the cell asks for, and then prints no result.
 
 ``--trace 0`` measures the cell's end-to-end metrics with the profiler off;
-``--trace 1`` runs one study of the window under the profiler (which keeps
-at most 6 Mi op events) and reports the per-layer metrics, the device's
-busy and window seconds and a breakdown.
+``--trace 1`` runs the part of the window the loop names (one study for the
+closed study loop) under the profiler (which keeps at most 6 Mi op events)
+and reports the per-layer metrics, the device's busy and window seconds and
+a breakdown.
 Every run checks its answers against the numpy reference once the window
 has closed; the numbers compared, each with its limit, close standard error
 and the result line (``checks``).

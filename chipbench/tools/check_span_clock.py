@@ -147,11 +147,11 @@ def study_report(trace, anns: list, records: list) -> dict:
 def check_seed(cell: dict, seed: int, devices) -> dict:
     from chipbench import harness, traffic
     from chipbench import trace as trace_mod
-    from chipbench.study_cell import StudyCell
     from repro import tracing
 
-    drv = StudyCell(harness.load_config(cell["config"]),
-                    traffic.load(cell["traffic"]), seed)
+    mix = traffic.load(cell["traffic"])
+    drv = harness._load_path("loops", mix["kind"]).Cell(
+        harness.load_config(cell["config"]), mix, seed, devices)
     drv.setup(0.0)
     tdir = tempfile.mkdtemp(prefix="span_clock_")
     try:

@@ -25,19 +25,19 @@ SLACK = 1.5
 
 
 def study_control(cfg, seed):
-    from chipbench import study_cell, traffic
+    from chipbench import harness
     from chipbench.data import snds
 
+    design = harness._load_path("loops", "closed_study").design(cfg["study"])
     spec = snds.StarSpec.from_config(cfg)
     host = snds.host_copy(snds.generate(spec, seed))
-    codes = traffic.study_codes(snds.SIZES_SEED, cfg["study"],
-                                spec.zipf_exponent)
-    want = study_cell.reference_study(host, spec, cfg["study"], codes)
-    ctl = study_cell.reference_study(host, spec, cfg["study"], codes,
-                                     lossy_capacity=SLACK)
+    codes = design.codes(cfg["study"], spec.zipf_exponent)
+    want = design.reference(host, spec, cfg["study"], codes)
+    ctl = design.reference(host, spec, cfg["study"], codes,
+                           lossy_capacity=SLACK)
     ctl["features"] = {"X": ctl["X"], "tokens": (ctl["tokens"],
                                                  ctl["mask"])}
-    bad = study_cell.compare_study(ctl, want, spec.n_patients)
+    bad = design.compare(ctl, want, spec.n_patients)
     return {"differing_outputs": len(bad), "which": bad}
 
 

@@ -1,19 +1,19 @@
-"""The benchmark's one traffic generator.
+"""Traffic mixes and the seeded draws they share.
 
-A traffic mix is a JSON file under ``chipbench/traffic/`` holding only
-parameters; its ``kind`` says which loop drives the cell:
+A traffic mix is a data file, ``chipbench/traffic/<name>.json``, holding
+only parameters.  Its ``kind`` names the loop that drives the cell,
+``chipbench/loops/<kind>.py``, which reads the rest of the mix and says
+in its docstring how it drives the cell.
 
-- ``closed_study``: one client runs the configuration's study again as soon
-  as the previous one is on the host, for the whole window.
-
-A new kind is a new loop beside ``study_cell.py`` and a branch of
-``harness._driver``.
+A new mix of an existing kind is a new data file.  A new kind is a new loop
+file beside the others; the harness finds it by the name, and no file that
+is already here changes.
 """
 from __future__ import annotations
 
 import json
 import os
-from typing import Dict, Mapping
+from typing import Dict
 
 import numpy as np
 
@@ -38,20 +38,3 @@ def popular_codes(rng: np.random.Generator, n: int, top: int,
     w = 1.0 / np.arange(1, top + 1, dtype=np.float64) ** exponent
     return np.sort(rng.choice(top, size=n, replace=False, p=w / w.sum())
                    ).astype(np.int32)
-
-
-def study_codes(seed: int, study: Mapping, zipf: float) -> Dict[str, np.ndarray]:
-    """The study's code lists for this seed: the drugs of interest by
-    popularity among the most popular codes, the fracture codes uniformly
-    among codes of middling rank (an outcome is rare)."""
-    r = rng_for(seed, 1)
-    lo, hi = study["fracture_code_ranks"]
-
-    def rare(n):
-        return np.sort(r.choice(np.arange(lo, hi), size=n, replace=False)
-                       ).astype(np.int32)
-
-    return {"prevalent": popular_codes(r, int(study["prevalent_drugs"]),
-                                       int(study["code_pool"]), zipf),
-            "fracture_acts": rare(int(study["fracture_act_codes"])),
-            "fracture_diags": rare(int(study["fracture_diag_codes"]))}
